@@ -11,12 +11,9 @@ Run inside the dry-run environment:
 """
 from __future__ import annotations
 
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
-
 import argparse
 import json
+import os
 from pathlib import Path
 
 import jax
@@ -151,6 +148,10 @@ def lower_hfl(cfg, shape, mesh, rules, K, pods=2):
 
 
 def main():
+    # Placeholder devices for the multi-pod mesh, set before the backend
+    # starts and never at import (see repro.launch.dryrun.main).
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-67b")
     ap.add_argument("--shape", default="train_4k")

@@ -100,6 +100,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write results as JSON to PATH")
     args = ap.parse_args()
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_convergence, bench_engine, bench_fleet,
                             bench_hetero, bench_hfl_vs_fl, bench_horizon,
                             bench_lambda, bench_serve, bench_sroa,

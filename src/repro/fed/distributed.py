@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.fed.hfl import HflConfig
 from repro.models import cnn
@@ -79,12 +78,12 @@ def make_distributed_global_iteration(mesh: Mesh, cnn_cfg: cnn.CnnConfig,
             "m,m...->...", den, e) / tot, edge)
         return w
 
-    shardmapped = shard_map(
+    shardmapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), client_spec, client_spec, client_spec, client_spec,
                   client_spec),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def global_iteration(w, x_u, y_u, mask_u, sizes, onehot, participate):

@@ -17,7 +17,6 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import sroa
@@ -52,13 +51,13 @@ def _sharded_solver(mesh: Mesh, cfg: sroa.SroaConfig, max_rounds: int,
                                        ladder, cp, tl)
         return jax.vmap(one)(cells, init, mask, lam_v, extras)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis),) * 5,
-                   out_specs=P(axis),
-                   # the engine is a lax.while_loop, which has no
-                   # replication rule — and needs none: every input and
-                   # output is fully sharded over the cell axis.
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis),) * 5,
+                       out_specs=P(axis),
+                       # the engine is a lax.while_loop, which has no
+                       # replication rule — and needs none: every input
+                       # and output is fully sharded over the cell axis.
+                       check_vma=False)
     return jax.jit(fn)
 
 

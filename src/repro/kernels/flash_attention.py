@@ -37,13 +37,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
     def body(kb, carry):
         acc, m, denom = carry
-        # The leading block index must be a shaped scalar: interpret mode's
-        # load discharge rule rejects raw Python ints.
-        zero = jnp.asarray(0, jnp.int32)
-        k = pl.load(k_ref, (zero, pl.dslice(kb * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (zero, pl.dslice(kb * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        rows = pl.ds(kb * block_k, block_k)
+        k = k_ref[0, rows, :].astype(jnp.float32)
+        v = v_ref[0, rows, :].astype(jnp.float32)
         s = q @ k.T                            # (block_q, block_k)
         k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
         mask = jnp.ones((block_q, block_k), bool)
@@ -73,8 +69,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, q_offset=0, window=None,
-                           block_q=128, block_k=128, interpret=True):
+                           block_q=128, block_k=128, interpret: bool):
     """q: (B, H, Tq, hd), k/v: (B, H, Tk, hd) with hd a multiple of 128.
+
+    ``interpret`` runs the kernel body in the Pallas interpreter (CPU) or
+    lowers it through Mosaic (TPU); :mod:`repro.kernels.ops` picks it from
+    the backend.
 
     Returns (B, H, Tq, hd) in q.dtype.
     """
@@ -109,5 +109,6 @@ def flash_attention_pallas(q, k, v, *, causal=True, q_offset=0, window=None,
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq_p, hd), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, Tq_p, hd)[:, :, :Tq, :]

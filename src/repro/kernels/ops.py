@@ -1,13 +1,16 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the CPU container the kernels run in ``interpret=True`` mode (the kernel
-body executes exactly, without Mosaic lowering); on a real TPU pass
-``interpret=False`` (or rely on the backend default) to get compiled
-kernels.  Model code selects these via ``ArchConfig.attn_impl='pallas'`` and
-``SroaConfig.use_pallas=True``.
+The mode follows the backend: on CPU the kernels run in the Pallas
+interpreter (the kernel body executes exactly, without Mosaic lowering);
+on a TPU they are lowered through Mosaic.  Any other backend is refused
+rather than silently interpreted.  The raw kernels take ``interpret`` as a
+required argument, so nothing reaches the chip in interpret mode by
+default.  Model code selects these via ``ArchConfig.attn_impl='pallas'``
+and ``SroaConfig.use_pallas=True`` / ``SroaConfig.fused=True``.
 """
 from __future__ import annotations
 
+import re
 from functools import partial
 
 import jax
@@ -19,8 +22,30 @@ from repro.kernels import sroa_bisect as _sb
 from repro.kernels import topk_moves as _tk
 
 
+_KERNEL_CALL = re.compile(
+    r'%([A-Za-z_][\w-]*?)(?:\.\d+)* = [^\n]*'
+    r'custom_call_target="tpu_custom_call"')
+
+
+def compiled_kernel_names(hlo_text: str) -> set[str]:
+    """Mosaic kernels in a compiled TPU program (``compiled.as_text()``).
+
+    Each kernel's ``pallas_call`` carries a stable ``name`` (``sroa_solve``,
+    ``topk_moves``, ...), which names its ``tpu_custom_call`` instruction.
+    A kernel that ran in interpret mode leaves no such instruction.
+    """
+    return set(_KERNEL_CALL.findall(hlo_text))
+
+
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"backend {backend!r} is neither")
 
 
 @partial(jax.jit, static_argnames=("iters", "interpret"))

@@ -20,8 +20,9 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
-                   block_rows: int = 8, interpret: bool = True):
-    """x: (..., d); scale: (d,)."""
+                   block_rows: int = 8, *, interpret: bool):
+    """x: (..., d); scale: (d,).  ``interpret``: Pallas interpreter (CPU)
+    or Mosaic lowering (TPU)."""
     orig_shape = x.shape
     d = x.shape[-1]
     rows = int(x.size // d)
@@ -40,5 +41,6 @@ def rmsnorm_pallas(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale)
     return out[:rows].reshape(orig_shape)

@@ -49,7 +49,7 @@ def _bisect_kernel(g_ref, t_ref, b_ref, o_ref, *, iters: int):
 
 def sroa_bisect_pallas(G: jnp.ndarray, target: jnp.ndarray, b_max,
                        iters: int = 42, *, block_rows: int = ROWS,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     """G, target: (N,) float32 -> smallest b with rate(b) >= target.
 
     Pads N up to a (block_rows x 128) tile multiple; grid over row blocks.
@@ -77,6 +77,7 @@ def sroa_bisect_pallas(G: jnp.ndarray, target: jnp.ndarray, b_max,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
+        name="sroa_bisect",
     )(G2, T2, bm)
     return out.reshape(-1)[:N]
 
@@ -103,7 +104,7 @@ def _bisect_kernel_vec(g_ref, t_ref, b_ref, o_ref, *, iters: int):
 def sroa_bisect_pallas_vec(G: jnp.ndarray, target: jnp.ndarray,
                            b_max: jnp.ndarray, iters: int = 42, *,
                            block_rows: int = ROWS,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """Fleet-batched inversion: per-element bandwidth caps.
 
     G, target, b_max: (N,) float32 where N is typically a flattened
@@ -129,6 +130,7 @@ def sroa_bisect_pallas_vec(G: jnp.ndarray, target: jnp.ndarray,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
+        name="sroa_bisect_vec",
     )(Gp.reshape(rows, LANES), Tp.reshape(rows, LANES),
       Bp.reshape(rows, LANES))
     return out.reshape(-1)[:N]
@@ -333,7 +335,7 @@ def sroa_solve_pallas(A, J, H, delta, h, f_max, p_max, B, b_max, N0, lam,
                       p_iters: int = 36, t_iters: int = 48,
                       eps0: float = 1e-4, eps1: float = 1e-4,
                       eps2: float = 1e-4, t_low: float = 1.0,
-                      t_up: float = 3e7, interpret: bool = True):
+                      t_up: float = 3e7, interpret: bool):
     """Fused SROA solve for P independent problems in one kernel launch.
 
     Per-user operands (A, J, H, delta, h, f_max, p_max): (P, N) float32.
@@ -384,6 +386,7 @@ def sroa_solve_pallas(A, J, H, delta, h, f_max, p_max, B, b_max, N0, lam,
         out_shape=[jax.ShapeDtypeStruct((Pt, Np), jnp.float32)] * 3
         + [jax.ShapeDtypeStruct((Pt, LANES), jnp.float32)],
         interpret=interpret,
+        name="sroa_solve",
     )(Ap, Jp, Hp, Dp, Gp, Fp, Pp, scal)
     return (b[:P, :N], f[:P, :N], p[:P, :N], stat[:P, 0], stat[:P, 1],
             stat[:P, 2], stat[:P, 3] > 0.5)

@@ -41,13 +41,13 @@ _LN2 = 0.6931471805599453
 def _topk_kernel(g_ref, h_ref, pm_ref, as_ref, mk_ref, scal_ref,
                  idx_ref, val_ref, *, k: int, M: int):
     g = g_ref[0]                              # (Np, Mp) gain
-    H = h_ref[0][:, None]                     # (Np, 1) upload bits
-    pm = pm_ref[0][:, None]                   # (Np, 1) max power
-    an = as_ref[0][:, None]                   # (Np, 1) current edge (i32)
-    mk = mk_ref[0][:, None]                   # (Np, 1) active mask (f32)
-    scal = scal_ref[0]                        # (8,)
-    N0 = scal[0]
-    b_ref = scal[1]
+    H = h_ref[0]                              # (Np, 1) upload bits
+    pm = pm_ref[0]                            # (Np, 1) max power
+    an = as_ref[0]                            # (Np, 1) current edge (i32)
+    mk = mk_ref[0]                            # (Np, 1) active mask (f32)
+    scal = scal_ref[0]                        # (1, 8)
+    N0 = scal[:, 0:1]                         # (1, 1)
+    b_ref = scal[:, 1:2]
 
     shape = g.shape
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
@@ -73,7 +73,7 @@ def _topk_kernel(g_ref, h_ref, pm_ref, as_ref, mk_ref, scal_ref,
     # Iterative top-k: k rounds of (global argmin, record, knock out).
     Mp = shape[1]
     flat = row * Mp + col
-    Kp = idx_ref.shape[1]
+    Kp = idx_ref.shape[-1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, Kp), 1)
 
     def body(i, carry):
@@ -88,12 +88,12 @@ def _topk_kernel(g_ref, h_ref, pm_ref, as_ref, mk_ref, scal_ref,
     idx0 = jnp.zeros((1, Kp), jnp.int32)
     val0 = jnp.full((1, Kp), _BIG, jnp.float32)
     _, idxv, valv = jax.lax.fori_loop(0, k, body, (score, idx0, val0))
-    idx_ref[...] = idxv
-    val_ref[...] = valv
+    idx_ref[0] = idxv
+    val_ref[0] = valv
 
 
 def topk_moves_pallas(gain, H, p_max, assign, mask, N0, B, *, k: int,
-                      interpret: bool = True):
+                      interpret: bool):
     """Top-k single-user moves for P independent cells in one launch.
 
     Args:
@@ -104,6 +104,8 @@ def topk_moves_pallas(gain, H, p_max, assign, mask, N0, B, *, k: int,
       mask:   (P, N) bool active users.
       N0, B:  (P,) f32 noise PSD and cell bandwidth budget.
       k:      static number of moves to keep.
+      interpret: run the kernel body in the Pallas interpreter (CPU) or
+              lower it through Mosaic (TPU).
     Returns:
       (user, dst, score): each (P, k); rows with ``score >= _BIG/2`` are
       padding (fewer than k valid moves existed).
@@ -118,9 +120,13 @@ def topk_moves_pallas(gain, H, p_max, assign, mask, N0, B, *, k: int,
     gp = jnp.pad(gain, ((0, 0), (0, n_pad), (0, m_pad)),
                  constant_values=1e-12)
 
+    # Per-user operands travel as (P, Np, 1) columns and per-problem ones
+    # as (P, 1, 8) rows: each grid step's block then spans the full last
+    # two dimensions, which is what Mosaic requires of a one-problem block.
     def pad_u(x, dtype, fill):
         x = jnp.asarray(x, dtype)
-        return jnp.pad(x, ((0, 0), (0, n_pad)), constant_values=fill)
+        x = jnp.pad(x, ((0, 0), (0, n_pad)), constant_values=fill)
+        return x[..., None]
 
     Hp = pad_u(H, jnp.float32, 0.0)
     pmp = pad_u(p_max, jnp.float32, 1.0)
@@ -131,20 +137,22 @@ def topk_moves_pallas(gain, H, p_max, assign, mask, N0, B, *, k: int,
                         1.0)
     b_ref = jnp.asarray(B, jnp.float32) / n_act
     scal = jnp.stack([jnp.broadcast_to(jnp.asarray(N0, jnp.float32), (P,)),
-                      b_ref] + [jnp.zeros((P,), jnp.float32)] * 6, axis=1)
+                      b_ref] + [jnp.zeros((P,), jnp.float32)] * 6,
+                     axis=1)[:, None, :]
 
     gspec = pl.BlockSpec((1, Np, Mp), lambda i: (i, 0, 0))
-    uspec = pl.BlockSpec((1, Np), lambda i: (i, 0))
-    sspec = pl.BlockSpec((1, 8), lambda i: (i, 0))
-    kspec = pl.BlockSpec((1, Kp), lambda i: (i, 0))
+    uspec = pl.BlockSpec((1, Np, 1), lambda i: (i, 0, 0))
+    sspec = pl.BlockSpec((1, 1, 8), lambda i: (i, 0, 0))
+    kspec = pl.BlockSpec((1, 1, Kp), lambda i: (i, 0, 0))
     idx, val = pl.pallas_call(
         functools.partial(_topk_kernel, k=k, M=M),
         grid=(P,),
         in_specs=[gspec, uspec, uspec, uspec, uspec, sspec],
         out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((P, Kp), jnp.int32),
-                   jax.ShapeDtypeStruct((P, Kp), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((P, 1, Kp), jnp.int32),
+                   jax.ShapeDtypeStruct((P, 1, Kp), jnp.float32)],
         interpret=interpret,
+        name="topk_moves",
     )(gp, Hp, pmp, asp, mkp, scal)
-    idx, val = idx[:, :k], val[:, :k]
+    idx, val = idx[:, 0, :k], val[:, 0, :k]
     return idx // Mp, idx % Mp, val

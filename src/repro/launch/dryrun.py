@@ -16,14 +16,9 @@ Usage:
 """
 from __future__ import annotations
 
-# The VERY FIRST action: force 512 placeholder host devices BEFORE any jax
-# import (jax locks the device count on first init).  Deliberately NOT set
-# globally (conftest/pyproject) — smoke tests and benches must see 1 device.
-import os  # noqa: E402
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -418,6 +413,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    # 512 placeholder host devices for the production meshes.  Set before
+    # the first device query (jax fixes the device count when its backend
+    # starts) and never at import: tests and the planning service import
+    # this module and must keep their own device view.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import compile_cache
+
 
 def plan_request(planner, scn, warm_assign=None, new_users=None,
                  mask=None) -> dict:
@@ -97,15 +99,12 @@ def _draw_serve_fleet(args):
     return spec, fleet, cfg
 
 
-def run_service(args) -> dict:
-    """The streaming ``--mode plan`` driver (repro.fleet.service)."""
-    import json
-
-    from repro.fleet.service import (DriftConfig, PlanningService,
-                                     ServiceConfig, run_load)
+def service_setup(args):
+    """(spec, fleet, SroaConfig, ServiceConfig) of the ``--mode plan``
+    control plane, drawn from ``--seed`` and the planning CLI knobs."""
+    from repro.fleet.service import DriftConfig, ServiceConfig
 
     spec, fleet, cfg = _draw_serve_fleet(args)
-    ladder = _serve_ladder(args)
     topo = None
     if args.topology_period:
         from repro.fleet.topology import TopologyConfig
@@ -117,8 +116,19 @@ def run_service(args) -> dict:
         max_rounds=args.plan_rounds, escape_iters=2,
         top_k=args.top_k, n_starts=args.n_starts,
         horizon=args.horizon, switch_cost=args.switch_cost,
-        ladder=ladder, topology_period=args.topology_period,
+        ladder=_serve_ladder(args), topology_period=args.topology_period,
         topology=topo)
+    return spec, fleet, cfg, svc_cfg
+
+
+def run_service(args) -> dict:
+    """Run the ``--mode plan`` streaming control plane."""
+    import json
+
+    from repro.fleet.service import PlanningService, run_load
+
+    spec, fleet, cfg, svc_cfg = service_setup(args)
+    ladder = svc_cfg.ladder
     mode = "replan-all" if args.replan_all else "drift-gated"
     if args.horizon > 1 or args.switch_cost:
         mode += (f", horizon K={args.horizon}"
@@ -209,7 +219,7 @@ def run_planner(args) -> dict:
     return {"sum_R": total_R, "stats": planner.stats}
 
 
-def main(argv=None):
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="lm", choices=("lm", "plan"))
     ap.add_argument("--arch", default="qwen1.5-0.5b")
@@ -280,7 +290,12 @@ def main(argv=None):
                     help="objective-degradation replan threshold")
     ap.add_argument("--req-rate", type=float, default=2.0,
                     help="streaming mode: Poisson plan requests per tick")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    compile_cache.enable()
 
     if args.mode == "plan":
         if args.no_stream or args.host_loop:
@@ -327,7 +342,8 @@ def main(argv=None):
     tps = args.new_tokens * B / dt
     gen = np.concatenate(out_tokens, 1)
     print(f"[decode] {args.new_tokens} steps x batch {B} in {dt:.2f}s "
-          f"-> {tps:.1f} tok/s (CPU, incl. compile)")
+          f"-> {tps:.1f} tok/s ({jax.devices()[0].platform}, "
+          f"incl. compile)")
     print(f"[sample] first sequence: {gen[0][:16].tolist()}")
     return {"tok_per_s": tps, "prefill_s": t_prefill}
 

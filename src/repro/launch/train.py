@@ -29,7 +29,7 @@ from repro.data.synthetic import DATASET_SHAPES
 from repro.fed import straggler
 from repro.fed.hfl import HflConfig, run_hfl
 from repro.models import cnn
-from repro.runtime import fault
+from repro.runtime import compile_cache, fault
 
 
 def main(argv=None):
@@ -49,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--straggler-quantile", type=float, default=0.9)
     ap.add_argument("--noniid-alpha", type=float, default=None)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     # ---- 1. scenario -------------------------------------------------
     spec = dataclasses.replace(

@@ -99,17 +99,16 @@ def moe_ffn(params, x, *, top_k: int, capacity_factor: float = 1.25,
     rules = getattr(shard, "rules", None)
     g_axes = rules.mesh_axes("moe_groups") if rules is not None else None
     if G > 1 and mesh is not None and g_axes is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         gspec = P(g_axes)
-        scatter_fn = shard_map(
+        scatter_fn = jax.shard_map(
             scatter_local, mesh=mesh,
             in_specs=(P(g_axes), P(g_axes), P(g_axes)),
-            out_specs=P(g_axes), check_rep=False)
-        gather_fn = shard_map(
+            out_specs=P(g_axes), check_vma=False)
+        gather_fn = jax.shard_map(
             gather_local, mesh=mesh,
             in_specs=(P(g_axes), P(g_axes), P(g_axes)),
-            out_specs=P(g_axes), check_rep=False)
+            out_specs=P(g_axes), check_vma=False)
     else:
         scatter_fn, gather_fn = scatter_local, gather_local
 

@@ -228,8 +228,7 @@ def test_sharded_solve_multidevice_parity():
     (including the pad-to-device-multiple path: C=3 on 2 devices)."""
     code = """
 import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
-                           + os.environ.get("XLA_FLAGS", ""))
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import dataclasses
 import numpy as np
 from repro.core import sroa, wireless
