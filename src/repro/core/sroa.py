@@ -223,6 +223,7 @@ def _invert_rate_dispatch(G, target, b_max, iters, use_pallas: bool):
 # --------------------------------------------------------------------------
 # Algorithm 2: optimal (b, f) with fixed (p, t)
 # --------------------------------------------------------------------------
+@jax.named_scope("sroa.alg2")
 def algorithm2(consts: SroaConstants, p: jnp.ndarray, t, B, b_max,
                f_max: jnp.ndarray, N0, cfg: SroaConfig):
     """Returns (b, f, b_sum). Lockstep bisection on f, inner inversion for b."""
@@ -263,6 +264,7 @@ def algorithm2(consts: SroaConstants, p: jnp.ndarray, t, B, b_max,
 # --------------------------------------------------------------------------
 # Algorithm 3: optimal p with fixed t
 # --------------------------------------------------------------------------
+@jax.named_scope("sroa.alg3")
 def algorithm3(consts: SroaConstants, t, B, b_max, f_max, p_max, N0,
                cfg: SroaConfig):
     """Returns (b, f, p, b_sum)."""
@@ -307,6 +309,7 @@ def _energy(consts: SroaConstants, b, f, p, N0):
     return jnp.sum(E_com + E_cmp) + consts.E_cloud_total
 
 
+@jax.named_scope("sroa.bounds")
 def _auto_bounds(consts: SroaConstants, B, f_max, p_max, N0, lam,
                  cfg: SroaConfig):
     """Derive [t_lo, t_up] for Algorithm 4 from the scenario itself.
@@ -423,12 +426,13 @@ def solve_constants_impl(consts: SroaConstants, B, b_max, f_max, p_max, N0,
             (b, f, p, t, R, b_sum), best)
         return t_lo, t_up, R_star, best, it + 1
 
-    # Seed "best" with the largest deadline (always feasible if anything is).
-    b0, f0, p0, bsum0, R0 = eval_t(t_up0)
-    init_best = (b0, f0, p0, t_up0, R0, bsum0)
-    R_init = jnp.where(bsum0 > B * (1.0 + 1e-3), _BIG, R0)
-    carry = (t_lo0, t_up0, R_init, init_best, 0)
-    _, _, R_star, best, _ = lax.while_loop(cond, body, carry)
+    with jax.named_scope("sroa.alg4"):
+        # Seed "best" with the largest deadline (always feasible if anything is).
+        b0, f0, p0, bsum0, R0 = eval_t(t_up0)
+        init_best = (b0, f0, p0, t_up0, R0, bsum0)
+        R_init = jnp.where(bsum0 > B * (1.0 + 1e-3), _BIG, R0)
+        carry = (t_lo0, t_up0, R_init, init_best, 0)
+        _, _, R_star, best, _ = lax.while_loop(cond, body, carry)
     b, f, p, t, R, b_sum = best
 
     if cfg.refine_iters > 0:

@@ -159,6 +159,7 @@ def fleet_constants(fleet: FleetScenario, assigns: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnames=("cfg",))
+@jax.named_scope("reprice")
 def solve_constants_batch(consts: SroaConstants, B, b_max, f_max, p_max, N0,
                           lam, cfg: sroa.SroaConfig = sroa.SroaConfig()
                           ) -> sroa.SroaResult:
@@ -166,6 +167,9 @@ def solve_constants_batch(consts: SroaConstants, B, b_max, f_max, p_max, N0,
 
     Every argument carries a leading batch axis: per-user leaves are
     (B, N), per-scenario scalars are (B,).  Results stack the same way.
+    Its device ops carry the ``reprice`` scope: on the served path this is
+    the re-pricing of :func:`solve_batch` (the scope must sit inside the
+    jitted function, since a scope around an eager call of it is lost).
     """
     def one(c, B_, bm, fm, pm, n0, l):
         return sroa.solve_constants(c, B_, bm, fm, pm, n0, l, cfg)

@@ -387,17 +387,23 @@ def engine_core(scn: Scenario, init_assign: jnp.ndarray, mask: jnp.ndarray,
         switch_cost = float(switch_cost)
 
     def body(st: _EngineState) -> _EngineState:
-        if top_k > 0:
-            cands, valid = _pruned_candidates(scn, st.current, mask, top_k)
-        else:
-            cands, valid = candidate_assigns_device(st.current, M, mask, em)
-        if horizon_mode:
-            res, ev, R_score = _score_horizon(scn, gain_stack, cands, mask,
-                                              lam, cfg, incumbent,
-                                              switch_cost)
-        else:
-            res, ev = _score_neighbourhood(scn, cands, mask, lam, cfg)
-            R_score = ev.R
+        with jax.named_scope("engine.nominate"):
+            if top_k > 0:
+                cands, valid = _pruned_candidates(scn, st.current, mask, top_k)
+            else:
+                cands, valid = candidate_assigns_device(st.current, M, mask, em)
+        with jax.named_scope("engine.score"):
+            if horizon_mode:
+                res, ev, R_score = _score_horizon(scn, gain_stack, cands,
+                                                  mask, lam, cfg, incumbent,
+                                                  switch_cost)
+            else:
+                res, ev = _score_neighbourhood(scn, cands, mask, lam, cfg)
+                R_score = ev.R
+        with jax.named_scope("engine.select"):
+            return select(st, cands, valid, R_score, res, ev)
+
+    def select(st, cands, valid, R_score, res, ev) -> _EngineState:
         Rv = jnp.where(valid, R_score, _BIG)
         j = jnp.argmin(Rv)                 # first minimum; index 0 on ties
         R0 = Rv[0]
@@ -469,11 +475,12 @@ def engine_core(scn: Scenario, init_assign: jnp.ndarray, mask: jnp.ndarray,
 
     # One final constants-space solve for the winning pattern (also covers
     # max_rounds == 0, where the loop never scored anything).
-    B = scn.B_open
-    consts = sroa_constants(scn, st.best_assign, mask)
-    res = sroa.solve_constants_impl(consts, B, B, scn.f_max, scn.p_max,
-                                    scn.N0, lam, cfg)
-    ev = evaluate(scn, st.best_assign, res.b, res.f, res.p, lam, mask)
+    with jax.named_scope("engine.final"):
+        B = scn.B_open
+        consts = sroa_constants(scn, st.best_assign, mask)
+        res = sroa.solve_constants_impl(consts, B, B, scn.f_max, scn.p_max,
+                                        scn.N0, lam, cfg)
+        ev = evaluate(scn, st.best_assign, res.b, res.f, res.p, lam, mask)
     # R stays the CURRENT-slot eq-15 cost of the winning pattern (what the
     # data plane reprices); R_search is the objective the descent actually
     # minimized, which the horizon path needs to compare restarts.
@@ -538,20 +545,26 @@ def _engine_core_comp(scn: Scenario, init_assign: jnp.ndarray,
         switch_cost = float(switch_cost)
 
     def body(st: _EngineStateComp) -> _EngineStateComp:
-        if top_k > 0:
-            cands, comps, valid = _pruned_candidates_comp(
-                scn, st.current, st.comp, mask, top_k, ladder)
-        else:
-            cands, comps, valid = _comp_candidates(
-                st.current, st.comp, M, n_levels, mask, em)
-        if horizon_mode:
-            res, ev, R_score = _score_horizon(scn, gain_stack, cands, mask,
-                                              lam, cfg, incumbent,
-                                              switch_cost, comps, ladder)
-        else:
-            res, ev = _score_neighbourhood(scn, cands, mask, lam, cfg,
-                                           comps, ladder)
-            R_score = ev.R
+        with jax.named_scope("engine.nominate"):
+            if top_k > 0:
+                cands, comps, valid = _pruned_candidates_comp(
+                    scn, st.current, st.comp, mask, top_k, ladder)
+            else:
+                cands, comps, valid = _comp_candidates(
+                    st.current, st.comp, M, n_levels, mask, em)
+        with jax.named_scope("engine.score"):
+            if horizon_mode:
+                res, ev, R_score = _score_horizon(scn, gain_stack, cands,
+                                                  mask, lam, cfg, incumbent,
+                                                  switch_cost, comps, ladder)
+            else:
+                res, ev = _score_neighbourhood(scn, cands, mask, lam, cfg,
+                                               comps, ladder)
+                R_score = ev.R
+        with jax.named_scope("engine.select"):
+            return select(st, cands, comps, valid, R_score, res, ev)
+
+    def select(st, cands, comps, valid, R_score, res, ev) -> _EngineStateComp:
         Rv = jnp.where(valid, R_score, _BIG)
         j = jnp.argmin(Rv)                 # first minimum; index 0 on ties
         R0 = Rv[0]
@@ -631,12 +644,14 @@ def _engine_core_comp(scn: Scenario, init_assign: jnp.ndarray,
         trace=trace0)
     st = lax.while_loop(cond, body, st0) if T > 0 else st0
 
-    B = scn.B_open
-    consts = sroa_constants(scn, st.best_assign, mask, st.best_comp, ladder)
-    res = sroa.solve_constants_impl(consts, B, B, scn.f_max, scn.p_max,
-                                    scn.N0, lam, cfg)
-    ev = evaluate(scn, st.best_assign, res.b, res.f, res.p, lam, mask,
-                  st.best_comp, ladder)
+    with jax.named_scope("engine.final"):
+        B = scn.B_open
+        consts = sroa_constants(scn, st.best_assign, mask, st.best_comp,
+                                ladder)
+        res = sroa.solve_constants_impl(consts, B, B, scn.f_max, scn.p_max,
+                                        scn.N0, lam, cfg)
+        ev = evaluate(scn, st.best_assign, res.b, res.f, res.p, lam, mask,
+                      st.best_comp, ladder)
     return EngineResult(assign=st.best_assign, R=ev.R, sroa=res,
                         rounds=st.rounds, escapes=st.escapes,
                         converged=st.converged, trace=st.trace,

@@ -26,7 +26,6 @@ histogram) accumulates in :mod:`repro.fleet.service.telemetry`.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import NamedTuple
 
 import jax
@@ -113,7 +112,8 @@ class PlanningService:
         self.queue = CoalescingQueue()
         self.telemetry = Telemetry()
         self.tick_idx = 0
-        self._bootstrap()
+        with self.telemetry.span("svc.bootstrap"):
+            self._bootstrap()
 
     # -------------------------------------------------------------- engine
     def _horizon_mode(self) -> bool:
@@ -221,38 +221,55 @@ class PlanningService:
 
     def _replan(self, idx: np.ndarray,
                 ev: dynamics.FleetEvents | None) -> None:
-        """One engine call re-searching the drifted cells (bucket-padded)."""
+        """One engine call re-searching the drifted cells (bucket-padded).
+
+        Counts the batched loop's trip (its slowest row's rounds), the
+        rounds of the real rows, the bucket's rows and cells, and escapes.
+        """
+        tel = self.telemetry
         k = idx.size
-        pidx = np.concatenate(
-            [idx, np.full(self._bucket(k) - k, idx[0], idx.dtype)])
-        jidx = jnp.asarray(pidx)
-        sub = jax.tree.map(lambda x: x[jidx], self.fleet)
-        init = icomp = None
-        if self.cfg.warm_start:
-            init = self.assigns[pidx].copy()
-            if ev is not None and ev.arrived[pidx].any():
-                # Churn arrivals have no searched assignment yet: seed them
-                # at their nearest edge (Alg 5 line 5) before the polish.
-                ne = np.asarray(fbatch.fleet_assignments(sub))
-                init = np.where(ev.arrived[pidx], ne, init)
-            init = jnp.asarray(init, jnp.int32)
-            if self._comp_on:
-                # Arrivals start uncompressed; survivors keep their level.
-                ic = self.comps[pidx].copy()
-                if ev is not None:
-                    ic = np.where(ev.arrived[pidx], 0, ic)
-                icomp = jnp.asarray(ic, jnp.int32)
-        # Receding-horizon warm start (D10): the previous window's winner
-        # rides as one extra restart row (engine re-homes it off closed
-        # edges), so warm MPC search never loses to a cold one.
-        tails = (jnp.asarray(self._tail[pidx], jnp.int32)
-                 if self._tail is not None else None)
-        out = self._engine(sub, init, rows=pidx, init_comps=icomp,
-                           tail_inits=tails)
-        self.assigns[idx] = np.asarray(out.assign)[:k]
-        self.comps[idx] = np.asarray(out.comp)[:k]
-        if self._tail is not None:
-            self._tail[idx] = np.asarray(out.assign)[:k]
+        with tel.span("svc.research.gather"):
+            pidx = np.concatenate(
+                [idx, np.full(self._bucket(k) - k, idx[0], idx.dtype)])
+            jidx = jnp.asarray(pidx)
+            sub = jax.tree.map(lambda x: x[jidx], self.fleet)
+            init = icomp = None
+            if self.cfg.warm_start:
+                init = self.assigns[pidx].copy()
+                if ev is not None and ev.arrived[pidx].any():
+                    # Churn arrivals have no searched assignment yet: seed
+                    # them at their nearest edge (Alg 5 line 5) before the
+                    # polish.
+                    ne = np.asarray(fbatch.fleet_assignments(sub))
+                    init = np.where(ev.arrived[pidx], ne, init)
+                init = jnp.asarray(init, jnp.int32)
+                if self._comp_on:
+                    # Arrivals start uncompressed; survivors keep their
+                    # level.
+                    ic = self.comps[pidx].copy()
+                    if ev is not None:
+                        ic = np.where(ev.arrived[pidx], 0, ic)
+                    icomp = jnp.asarray(ic, jnp.int32)
+            # Receding-horizon warm start (D10): the previous window's
+            # winner rides as one extra restart row (engine re-homes it off
+            # closed edges), so warm MPC search never loses to a cold one.
+            tails = (jnp.asarray(self._tail[pidx], jnp.int32)
+                     if self._tail is not None else None)
+        with tel.span("svc.research.engine"):
+            out = self._engine(sub, init, rows=pidx, init_comps=icomp,
+                               tail_inits=tails)
+            assign = np.asarray(out.assign)
+        with tel.span("svc.research.scatter"):
+            self.assigns[idx] = assign[:k]
+            self.comps[idx] = np.asarray(out.comp)[:k]
+            if self._tail is not None:
+                self._tail[idx] = assign[:k]
+            rounds = np.asarray(out.rounds)
+            tel.count("research.trip", rounds.max())
+            tel.count("research.row_rounds", rounds[:k].sum())
+            tel.count("research.rows", pidx.size)
+            tel.count("research.cells", k)
+            tel.count("research.escapes", np.asarray(out.escapes)[:k].sum())
 
     # ------------------------------------------------------------- topology
     def _redesign_topology(self) -> int:
@@ -295,106 +312,127 @@ class PlanningService:
         return self.queue.submit(key=self.tick_idx)
 
     def tick(self, advance: bool = True) -> TickRecord:
-        """One control-plane tick: dynamics, drift, replan, serve."""
-        t0 = time.perf_counter()
-        C = self.fleet.C
-        prev_assigns = self.assigns.copy()
-        prev_active = np.asarray(self.state.active, bool).copy()
-        ev = None
-        if advance:
-            cm = self.rng.uniform(size=C) < self.cfg.event_rate
-            self.fleet, self.state, ev = dynamics.fleet_step(
-                self.fleet, self.state, self.rng, cfg=self.cfg.stream,
-                spec=self.spec, cell_mask=cm)
+        """One control-plane tick: dynamics, drift, replan, serve.
 
-        # Slow-timescale topology redesign (D12): every P ticks, re-open the
-        # edge placement question under the drifted geometry.
-        topo_moves = 0
-        if (self.cfg.topology_period and self.tick_idx > 0
-                and self.tick_idx % self.cfg.topology_period == 0
-                and self.fleet.cells.edge_mask is not None):
-            topo_moves = self._redesign_topology()
+        Each stage runs in a telemetry span (``svc.*``); together the
+        top-level spans cover the whole tick.
+        """
+        tel = self.telemetry
+        with tel.tick():
+            C = self.fleet.C
+            with tel.span("svc.dynamics"):
+                prev_assigns = self.assigns.copy()
+                prev_active = np.asarray(self.state.active, bool).copy()
+                ev = None
+                if advance:
+                    cm = self.rng.uniform(size=C) < self.cfg.event_rate
+                    self.fleet, self.state, ev = dynamics.fleet_step(
+                        self.fleet, self.state, self.rng,
+                        cfg=self.cfg.stream, spec=self.spec, cell_mask=cm)
+                gain_now = np.asarray(self.fleet.cells.gain, np.float64)
 
-        gain_now = np.asarray(self.fleet.cells.gain, np.float64)
-        alloc = self._reprice()
-        alloc_calls = 1
-        report = fdrift.score(gain_now, self.gain_ref, self.state.active,
-                              np.asarray(alloc.R), self.R_ref,
-                              self.cfg.drift)
-        # Churn forces a re-search both ways: arrivals need a first
-        # assignment, and departures free bandwidth/compute the survivors'
-        # optimum shifts onto — drift scoring alone can miss either (the
-        # repriced R of a shrunken cell DROPS, which never trips the
-        # objective gate).
-        forced = (ev.arrived.any(axis=1) | ev.departed.any(axis=1)
-                  if ev is not None else np.zeros(C, bool))
-        if self.cfg.replan_all:
-            idx = np.arange(C)
-        else:
-            idx = np.flatnonzero(report.replan | forced)
+            # Slow-timescale topology redesign (D12): every P ticks,
+            # re-open the edge placement question under the drifted
+            # geometry (it moves sites, never the channel gains).
+            topo_moves = 0
+            if (self.cfg.topology_period and self.tick_idx > 0
+                    and self.tick_idx % self.cfg.topology_period == 0
+                    and self.fleet.cells.edge_mask is not None):
+                with tel.span("svc.topology"):
+                    topo_moves = self._redesign_topology()
 
-        engine_calls = 0
-        if idx.size:
-            self._replan(idx, ev)
-            engine_calls = 1
-            alloc = self._reprice()
-            alloc_calls += 1
-            self.gain_ref[idx] = gain_now[idx]
-        self.alloc = alloc
-        R_now = np.asarray(alloc.R, np.float64)
-        if idx.size:
-            self.R_ref[idx] = R_now[idx]
-            self._install_cache(idx)
-        sum_R = float(R_now.sum())
+            with tel.span("svc.reprice"):
+                alloc = self._reprice()
+            alloc_calls = 1
+            with tel.span("svc.drift"):
+                report = fdrift.score(gain_now, self.gain_ref,
+                                      self.state.active, np.asarray(alloc.R),
+                                      self.R_ref, self.cfg.drift)
+                # Churn forces a re-search both ways: arrivals need a first
+                # assignment, and departures free bandwidth/compute the
+                # survivors' optimum shifts onto — drift scoring alone can
+                # miss either (the repriced R of a shrunken cell DROPS,
+                # which never trips the objective gate).
+                forced = (ev.arrived.any(axis=1) | ev.departed.any(axis=1)
+                          if ev is not None else np.zeros(C, bool))
+                if self.cfg.replan_all:
+                    idx = np.arange(C)
+                else:
+                    idx = np.flatnonzero(report.replan | forced)
 
-        groups = self.queue.drain()
-        tick_ms = (time.perf_counter() - t0) * 1e3
-        replanned = set(int(i) for i in idx)
-        base = {
-            "tick": self.tick_idx,
-            "objective": sum_R,
-            "R": R_now.tolist(),
-            "assign": self.assigns.tolist(),
-            "replanned": sorted(replanned),
-            "comp": self.comps.tolist() if self._comp_on else None,
-            "cached": [i not in replanned for i in range(C)],
-            "drift_channel": report.channel.tolist(),
-            "plan_ms": tick_ms,
-        }
-        served = 0
-        coalesced = 0
-        for reqs in groups.values():
-            resp = dict(base, coalesced=len(reqs))
-            coalesced = max(coalesced, len(reqs))
-            for r in reqs:
-                self.telemetry.record_request(r.resolve(resp))
-                served += 1
-        changed = int(ev.changed.sum()) if ev is not None else 0
-        # A handover is an edge change for a user active in BOTH plans:
-        # churn arrivals (first edge) and departures (stale slot) are free.
-        handovers = int(((prev_assigns != self.assigns)
-                         & prev_active
-                         & np.asarray(self.state.active, bool)).sum())
-        active = np.asarray(self.state.active, bool)
-        tiers = np.asarray(self.fleet.cells.tier)
-        # Tier ids of every active user in a re-searched cell: the replan
-        # burden heterogeneity telemetry (D11) — who pays for churn/drift.
-        tier_replans = (tiers[idx][active[idx]] if idx.size else None)
-        comp_levels = (self.comps[active] if self._comp_on else None)
-        self.telemetry.record_tick(
-            n_cells=C, n_changed=changed, n_replanned=idx.size,
-            engine_calls=engine_calls, alloc_calls=alloc_calls,
-            sum_R=sum_R, tick_ms=tick_ms, drift_scores=report.channel,
-            objective_scores=report.objective, coalesced=coalesced,
-            handovers=handovers, tier_replans=tier_replans,
-            comp_levels=comp_levels)
-        rec = TickRecord(tick=self.tick_idx, changed=changed,
-                         replanned=np.asarray(idx),
-                         engine_calls=engine_calls, sum_R=sum_R,
-                         served=served, coalesced=coalesced,
-                         tick_ms=tick_ms, drift=report,
-                         handovers=handovers, topo_moves=topo_moves)
-        self.tick_idx += 1
+            engine_calls = 0
+            if idx.size:
+                with tel.span("svc.research"):
+                    self._replan(idx, ev)
+                engine_calls = 1
+                with tel.span("svc.reprice"):
+                    alloc = self._reprice()
+                alloc_calls += 1
+            with tel.span("svc.install"):
+                self.alloc = alloc
+                R_now = np.asarray(alloc.R, np.float64)
+                if idx.size:
+                    self.gain_ref[idx] = gain_now[idx]
+                    self.R_ref[idx] = R_now[idx]
+                    self._install_cache(idx)
+                    tel.count("install.cells", idx.size)
+                sum_R = float(R_now.sum())
+
+            with tel.span("svc.respond"):
+                groups = self.queue.drain()
+                tick_ms = tel.tick_elapsed_ms()
+                replanned = set(int(i) for i in idx)
+                base = {
+                    "tick": self.tick_idx,
+                    "objective": sum_R,
+                    "R": R_now.tolist(),
+                    "assign": self.assigns.tolist(),
+                    "replanned": sorted(replanned),
+                    "comp": self.comps.tolist() if self._comp_on else None,
+                    "cached": [i not in replanned for i in range(C)],
+                    "drift_channel": report.channel.tolist(),
+                    "plan_ms": tick_ms,
+                }
+                served = 0
+                coalesced = 0
+                for reqs in groups.values():
+                    resp = dict(base, coalesced=len(reqs))
+                    coalesced = max(coalesced, len(reqs))
+                    for r in reqs:
+                        tel.record_request(r.resolve(resp))
+                        served += 1
+                tel.count("serve.requests", served)
+
+            with tel.span("svc.telemetry"):
+                changed = int(ev.changed.sum()) if ev is not None else 0
+                # A handover is an edge change for a user active in BOTH
+                # plans: churn arrivals (first edge) and departures (stale
+                # slot) are free.
+                active = np.asarray(self.state.active, bool)
+                handovers = int(((prev_assigns != self.assigns)
+                                 & prev_active & active).sum())
+                tiers = np.asarray(self.fleet.cells.tier)
+                # Tier ids of every active user in a re-searched cell: the
+                # replan burden heterogeneity telemetry (D11) — who pays
+                # for churn/drift.
+                tier_replans = (tiers[idx][active[idx]] if idx.size
+                                else None)
+                comp_levels = (self.comps[active] if self._comp_on else None)
+                tel.record_tick(
+                    n_cells=C, n_changed=changed, n_replanned=idx.size,
+                    engine_calls=engine_calls, alloc_calls=alloc_calls,
+                    sum_R=sum_R, tick_ms=tick_ms,
+                    drift_scores=report.channel,
+                    objective_scores=report.objective, coalesced=coalesced,
+                    handovers=handovers, tier_replans=tier_replans,
+                    comp_levels=comp_levels)
+                rec = TickRecord(tick=self.tick_idx, changed=changed,
+                                 replanned=np.asarray(idx),
+                                 engine_calls=engine_calls, sum_R=sum_R,
+                                 served=served, coalesced=coalesced,
+                                 tick_ms=tick_ms, drift=report,
+                                 handovers=handovers, topo_moves=topo_moves)
+                self.tick_idx += 1
         return rec
 
     def run(self, ticks: int) -> list[TickRecord]:

@@ -14,12 +14,26 @@ Throughput is counted two ways:
   cells.  This is the control plane's capacity metric.
 * ``requests_per_s`` — plan requests answered per wall second (requests
   coalesce per tick, so this tracks offered load, not capacity).
+
+Spans and counters say where a tick's time goes.  :meth:`Telemetry.span`
+times a block on the host clock (``time.perf_counter``) and opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+shows the span beside the device ops on one clock; :meth:`Telemetry.count`
+adds to a counter.  Inside :meth:`Telemetry.tick` both land in the tick's
+record, :attr:`Telemetry.last_tick`, and fold into running sums when the
+tick ends; a span closed outside any tick (the bootstrap) is kept apart in
+:attr:`Telemetry.setup_ms`, which :meth:`reset` leaves alone.  Spans are
+recorded by the thread that runs the ticks.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import dataclasses
 import json
 import time
 
+import jax
 import numpy as np
 
 # Drift histogram bin edges.  The leading -inf edge is an underflow bin:
@@ -30,12 +44,33 @@ import numpy as np
 DRIFT_BINS = (-np.inf, 0.0, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5, 1.0,
               np.inf)
 
+# Ticks whose per-span totals the span percentiles are taken over: means
+# come from running sums, so a long-running service keeps bounded memory.
+SPAN_WINDOW = 1024
+
+
+@dataclasses.dataclass
+class TickSpans:
+    """One tick's raw record on the host clock (``time.perf_counter`` s).
+
+    ``spans`` holds ``(name, start, end, parent)`` for every span closed in
+    the tick, ``parent`` being the name of the span it was opened in (None
+    at the top level); ``counters`` what the tick added to each counter.
+    """
+
+    t0: float
+    t1: float | None = None
+    spans: list = dataclasses.field(default_factory=list)
+    counters: dict = dataclasses.field(default_factory=dict)
+
 
 class Telemetry:
     """Rolling counters for the planning control plane."""
 
     def __init__(self, drift_bins: tuple = DRIFT_BINS):
         self.drift_bins = np.asarray(drift_bins, np.float64)
+        self.setup_ms: dict[str, float] = {}   # spans closed outside ticks
+        self._open: list[str] = []             # names of the open spans
         self.reset()
 
     def reset(self) -> None:
@@ -62,6 +97,67 @@ class Telemetry:
         # mix is a state, not a rate).
         self.tier_replans: dict[int, int] = {}
         self.comp_hist: dict[int, int] = {}
+        # Spans and counters: the last tick's raw record, and per name the
+        # summed milliseconds over ticks plus the recent per-tick totals.
+        self.last_tick: TickSpans | None = None
+        self._in_tick = False
+        self.span_ticks = 0
+        self.span_sum_ms: dict[str, float] = {}
+        self.span_recent: collections.deque = collections.deque(
+            maxlen=SPAN_WINDOW)
+        self.counters: dict[str, int] = {}
+
+    # ----------------------------------------------------- spans / counters
+    @contextlib.contextmanager
+    def tick(self):
+        """Open a tick's record; its spans and counters land in
+        :attr:`last_tick`, and fold into the running sums when it ends."""
+        self.last_tick = TickSpans(t0=time.perf_counter())
+        self._in_tick = True
+        try:
+            yield self.last_tick
+        finally:
+            rec = self.last_tick
+            rec.t1 = time.perf_counter()
+            self._in_tick = False
+            per_tick: dict[str, float] = {}
+            for name, a, b, _ in rec.spans:
+                per_tick[name] = per_tick.get(name, 0.0) + (b - a) * 1e3
+            for name, ms in per_tick.items():
+                self.span_sum_ms[name] = self.span_sum_ms.get(name, 0.0) + ms
+            self.span_recent.append(per_tick)
+            self.span_ticks += 1
+
+    def tick_elapsed_ms(self) -> float:
+        """Milliseconds since the open tick began."""
+        return (time.perf_counter() - self.last_tick.t0) * 1e3
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        """Time the block on the host clock, under ``name`` in the profiler
+        trace too.  Adds no device sync: a span ends where its block does."""
+        parent = self._open[-1] if self._open else None
+        self._open.append(name)
+        a = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+        finally:
+            b = time.perf_counter()
+            self._open.pop()
+            if self._in_tick:
+                self.last_tick.spans.append((name, a, b, parent))
+            else:
+                self.setup_ms[name] = (self.setup_ms.get(name, 0.0)
+                                       + (b - a) * 1e3)
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to counter ``name`` (and to the open tick's record)."""
+        n = int(n)
+        self.counters[name] = self.counters.get(name, 0) + n
+        if self._in_tick:
+            rec = self.last_tick.counters
+            rec[name] = rec.get(name, 0) + n
 
     # ------------------------------------------------------------- recording
     def record_request(self, latency_ms: float) -> None:
@@ -116,6 +212,16 @@ class Telemetry:
         return {f"<{hi:g}": int(n)
                 for hi, n in zip(self.drift_bins[1:], counts)}
 
+    def _span_stats(self) -> dict:
+        """Per span name: mean, p50 and p99 of its milliseconds per tick."""
+        out = {}
+        for name in sorted(self.span_sum_ms):
+            recent = [t.get(name, 0.0) for t in self.span_recent]
+            out[name] = {"mean": self.span_sum_ms[name] / self.span_ticks,
+                         "p50": self._pct(recent, 50),
+                         "p99": self._pct(recent, 99)}
+        return out
+
     def snapshot(self) -> dict:
         elapsed = max(time.perf_counter() - self.t0, 1e-9)
         lat = self.latencies_ms
@@ -146,6 +252,11 @@ class Telemetry:
                                  in sorted(self.tier_replans.items())},
             "compression_hist": {str(lv): n for lv, n
                                  in sorted(self.comp_hist.items())},
+            # ms per tick of each span; counters summed over the window;
+            # spans closed outside ticks (the bootstrap), in ms
+            "spans": self._span_stats(),
+            "counters": dict(sorted(self.counters.items())),
+            "setup_ms": dict(sorted(self.setup_ms.items())),
         }
 
     def emit(self, fh=None) -> str:

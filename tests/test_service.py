@@ -375,3 +375,138 @@ def test_tick_reports_handovers_of_surviving_users_only():
     want = int(((prev != svc.assigns) & svc.state.active).sum())
     assert rec2.handovers == want
     assert svc.telemetry.handovers == want
+
+
+# ------------------------------------------------------- spans and counters
+TOP_SPANS = ("svc.dynamics", "svc.reprice", "svc.drift", "svc.install",
+             "svc.respond", "svc.telemetry")
+RESEARCH_SPANS = ("svc.research", "svc.research.gather",
+                  "svc.research.engine", "svc.research.scatter")
+
+
+@pytest.mark.parametrize("replan_all", [True, False])
+def test_tick_record_holds_every_stage_span(replan_all):
+    """A tick that re-searches records every stage span, the re-pricing
+    twice; one that does not (nothing drifted) records no re-search."""
+    svc = make_service(replan_all=replan_all)
+    svc.submit()
+    rec = svc.tick(advance=False)
+    tick = svc.telemetry.last_tick
+    names = [n for n, *_ in tick.spans]
+    searched = rec.replanned.size > 0
+    assert searched == replan_all
+    want = set(TOP_SPANS) | (set(RESEARCH_SPANS) if searched else set())
+    assert set(names) == want
+    assert names.count("svc.reprice") == (2 if searched else 1)
+    assert not any(n == "tick" or n.startswith("tick.") for n in names)
+    assert tick.counters["serve.requests"] == rec.served == 1
+    assert ("install.cells" in tick.counters) == searched
+    assert rec.tick_ms <= (tick.t1 - tick.t0) * 1e3
+
+
+def test_top_level_spans_tile_the_tick():
+    """Top-level spans are disjoint, lie inside the tick and cover it;
+    each child lies inside its parent.  Coverage is judged on the median
+    tick: the thread being descheduled between two spans is no stage."""
+    import gc
+    svc = make_service(replan_all=True)
+    gc.disable()          # a collection between two spans is no stage
+    try:
+        records = []
+        for _ in range(3):
+            svc.tick()
+            records.append(svc.telemetry.last_tick)
+    finally:
+        gc.enable()
+    cover = []
+    for rec in records:
+        top = sorted((a, b) for _, a, b, parent in rec.spans
+                     if parent is None)
+        assert rec.t0 <= top[0][0] and top[-1][1] <= rec.t1
+        assert all(b0 <= a1 for (_, b0), (a1, _) in zip(top, top[1:]))
+        cover.append(sum(b - a for a, b in top) / (rec.t1 - rec.t0))
+        outer = {n: (a, b) for n, a, b, parent in rec.spans
+                 if parent is None}
+        for n, a, b, parent in rec.spans:
+            if parent is not None:
+                pa, pb = outer[parent]
+                assert pa <= a <= b <= pb, n
+    assert sorted(cover)[1] >= 0.99
+
+
+def test_research_counters_read_the_engine_result():
+    """The trip is the slowest row of the whole bucket; row-rounds and
+    escapes sum over the real rows only."""
+    svc = make_service()
+    outs = []
+    engine = svc._engine
+
+    def kept(*a, **k):
+        outs.append(engine(*a, **k))
+        return outs[-1]
+    svc._engine = kept
+    idx = np.array([2, 0, 3])                  # 3 cells -> a bucket of 4
+    with svc.telemetry.tick() as tick:
+        svc._replan(idx, None)
+    rounds = np.asarray(outs[0].rounds)
+    escapes = np.asarray(outs[0].escapes)
+    assert rounds.shape == (4,)
+    assert tick.counters == {
+        "research.trip": int(rounds.max()),
+        "research.row_rounds": int(rounds[:3].sum()),
+        "research.rows": 4, "research.cells": 3,
+        "research.escapes": int(escapes[:3].sum())}
+    assert svc.telemetry.counters == tick.counters
+
+
+def _engine_program(ladder):
+    fleet = make_fleet()
+    return fengine.solve_fleet_assignments.lower(
+        fleet, fbatch.fleet_assignments(fleet), LAM, CFG, 2, 1,
+        ladder=ladder)
+
+
+def _reprice_program(_):
+    fleet = make_fleet()
+    return jax.jit(fbatch.solve_batch, static_argnames=("cfg", "ladder")
+                   ).lower(fleet, fbatch.fleet_assignments(fleet), LAM, CFG)
+
+
+SROA_SCOPES = ("sroa.bounds", "sroa.alg4", "sroa.alg3", "sroa.alg2")
+ENGINE_SCOPES = ("engine.nominate", "engine.score", "engine.select",
+                 "engine.final") + SROA_SCOPES
+
+
+@pytest.mark.parametrize("lower,ladder,scopes", [
+    (_engine_program, None, ENGINE_SCOPES),
+    (_engine_program, "two-rung", ENGINE_SCOPES),
+    (_reprice_program, None, ("reprice",) + SROA_SCOPES),
+], ids=["engine", "engine-compression", "reprice"])
+def test_device_programs_name_their_scopes(lower, ladder, scopes):
+    from repro.fed import compression as comp_lib
+    if ladder is not None:
+        ladder = comp_lib.default_ladder()
+    import re
+    text = lower(ladder).as_text(debug_info=True)
+    names = {w for loc in re.findall(r'loc\("([^"]*)"', text)
+             for w in re.findall(r"[\w.]+", loc)}
+    assert set(scopes) <= names
+
+
+def test_snapshot_exports_spans_and_counters_and_roundtrips():
+    import json
+    svc = make_service(replan_all=True)
+    boot = svc.telemetry.setup_ms["svc.bootstrap"]
+    assert boot > 0
+    svc.run(2)
+    snap = json.loads(svc.telemetry.emit())
+    assert set(snap["spans"]) == set(TOP_SPANS) | set(RESEARCH_SPANS)
+    for s in snap["spans"].values():
+        assert set(s) == {"mean", "p50", "p99"} and s["p99"] >= s["p50"] > 0
+    assert snap["counters"]["research.cells"] == 2 * svc.fleet.C
+    assert snap["counters"]["research.trip"] >= 2
+    assert snap["setup_ms"] == {"svc.bootstrap": boot}
+    svc.telemetry.reset()          # a new window keeps the set-up record
+    snap = svc.telemetry.snapshot()
+    assert snap["spans"] == {} and snap["counters"] == {}
+    assert snap["setup_ms"] == {"svc.bootstrap": boot}
