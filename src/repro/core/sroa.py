@@ -16,9 +16,12 @@ both relative-tolerance and iteration-cap stopping, so a full solve is one
 XLA computation (jit-able, differentiable in the leaves we don't branch on).
 
 The innermost bandwidth inversion is the compute hot-spot when planning for
-fleet-scale N (the paper's complexity analysis §IV-C is dominated by it);
-``repro.kernels.sroa_bisect`` provides a Pallas TPU kernel for it, validated
-against :func:`invert_rate` (the pure-jnp oracle) in tests.
+fleet-scale N (the paper's complexity analysis §IV-C is dominated by it).
+:func:`invert_rate` unrolls its fixed-trip bisection into the body of a
+one-trip device loop, so XLA fuses all its steps into one op per call
+rather than launching one op per step; ``repro.kernels.sroa_bisect``
+provides a Pallas TPU kernel for it, validated against :func:`invert_rate`
+(the pure-jnp oracle) in tests.
 """
 from __future__ import annotations
 
@@ -79,10 +82,17 @@ def invert_rate(G: jnp.ndarray, target: jnp.ndarray, b_max,
 
     Returns b_max where even b_max cannot reach the target (infeasible);
     callers detect this via ``rate_fn(b, G) < target``.
+
+    The ``iters`` steps are unrolled, so XLA fuses them into one op, inside
+    a ``while_loop`` that runs once on a flag.  XLA cannot see the trip
+    count and keeps that loop, so the result reaches the caller through
+    memory, as the step-by-step loop's did.  Unrolled inline instead, XLA
+    would fold the producers of ``G`` and ``target`` into the steps
+    (``(x / y) / b`` -> ``x / (y * b)``) and fuse the caller's ``b_sum``
+    reduce with them, changing its summation order; both change bits.
+    Kept, the result and the caller's sums are bitwise the rolled loop's.
     """
     feas = rate_fn(jnp.full_like(G, b_max), G) >= target
-    lo = jnp.zeros_like(G)
-    hi = jnp.full_like(G, b_max)
 
     def body(_, lohi):
         lo, hi = lohi
@@ -90,7 +100,14 @@ def invert_rate(G: jnp.ndarray, target: jnp.ndarray, b_max,
         ok = rate_fn(mid, G) >= target
         return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
 
-    lo, hi = lax.fori_loop(0, iters, body, (lo, hi))
+    def bisect(carry):
+        _, hi = carry
+        _, hi = lax.fori_loop(0, iters, body, (jnp.zeros_like(G), hi),
+                              unroll=True)
+        return False, hi
+
+    _, hi = lax.while_loop(lambda carry: carry[0], bisect,
+                           (True, jnp.full_like(G, b_max)))
     return jnp.where(feas, hi, b_max)
 
 

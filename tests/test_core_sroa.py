@@ -1,8 +1,11 @@
 """Unit + property tests for the paper's cost model and SROA (Algs 2-4)."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from _hypothesis_compat import given, settings, st
 
 from repro.core import baselines, sroa, system_model, wireless
@@ -94,6 +97,102 @@ def test_invert_rate_property(G, frac):
 def test_invert_rate_infeasible_returns_bmax():
     b = sroa.invert_rate(jnp.asarray([1e3]), jnp.asarray([1e9]), 1e6)
     assert float(b[0]) == pytest.approx(1e6)
+
+
+def _rolled_invert_rate(G, target, b_max, iters=42):
+    """Step-by-step oracle: the same bisection as a rolled device loop."""
+    feas = sroa.rate_fn(jnp.full_like(G, b_max), G) >= target
+    lo = jnp.zeros_like(G)
+    hi = jnp.full_like(G, b_max)
+
+    def body(_, lohi):
+        lo, hi = lohi
+        mid = 0.5 * (lo + hi)
+        ok = sroa.rate_fn(mid, G) >= target
+        return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
+
+    lo, hi = lax.fori_loop(0, iters, body, (lo, hi))
+    return jnp.where(feas, hi, b_max)
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+def _inversion_case(kind, shape, seed=0):
+    """(G, target, b_max, b_max broadcast to ``shape``) for one edge of the
+    inversion; b_max is a scalar, or one per cell for a (C, A, N) shape."""
+    rng = np.random.default_rng(seed)
+    G = (10.0 ** rng.uniform(2.0, 10.0, shape)).astype(np.float32)
+    cells = shape[:1] if len(shape) > 1 else ()       # one b_max per cell
+    b_max = (10.0 ** rng.uniform(5.0, 7.0, cells)).astype(np.float32)
+    bm = np.reshape(b_max, cells + (1,) * (len(shape) - len(cells)))
+    sup = bm * np.log1p(G / bm) / np.log(2.0)
+    target = {
+        "reachable": rng.uniform(0.01, 0.95, shape) * sup,
+        "unreachable": 1.5 * sup,                      # b_max returned
+        "big": np.full(shape, sroa._BIG),
+        "zero": np.where(rng.uniform(size=shape) < 0.5, 0.0, sup),
+    }[kind].astype(np.float32)
+    if kind == "zero":                                 # b = 0 and G = 0 edges
+        G = np.where(rng.uniform(size=shape) < 0.5, 0.0, G).astype(np.float32)
+    return (jnp.asarray(G), jnp.asarray(target), jnp.asarray(b_max),
+            np.broadcast_to(bm, shape))
+
+
+@pytest.mark.parametrize("iters", [30, 42])
+@pytest.mark.parametrize("shape", [(8,), (2, 3, 8)])
+@pytest.mark.parametrize("kind", ["reachable", "unreachable", "big", "zero"])
+def test_invert_rate_bitwise_rolled_oracle(kind, shape, iters):
+    """The unrolled inversion is bitwise the step-by-step rolled loop,
+    alone and under the engine's double vmap (cells x candidates)."""
+    G, target, b_max, b_max_full = _inversion_case(kind, shape)
+
+    def call(fn):
+        one = partial(fn, iters=iters)
+        if len(shape) == 1:
+            return jax.jit(one)(G, target, b_max)
+        inner = jax.vmap(one, in_axes=(0, 0, None))
+        return jax.jit(jax.vmap(inner))(G, target, b_max)
+
+    got = call(sroa.invert_rate)
+    want = call(_rolled_invert_rate)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    if kind == "unreachable":
+        np.testing.assert_array_equal(_bits(got), _bits(b_max_full))
+
+
+def test_solve_unrolled_inversion_bitwise_rolled(monkeypatch):
+    """A double-vmapped full solve (cells x candidate assignments) gives
+    bitwise the same b, f, p, t, R as the same solve on the rolled oracle."""
+    spec = wireless.ScenarioSpec(N=8, M=3)
+    cfg = sroa.SroaConfig(b_iters=30, f_iters=16, p_iters=14, t_iters=20)
+    cells = [wireless.draw_scenario(s, spec) for s in (0, 1)]
+    rng = np.random.default_rng(0)
+    consts, args = [], []
+    for scn in cells:
+        assigns = jnp.stack(
+            [wireless.nearest_edge_assignment(scn)]
+            + [jnp.asarray(rng.integers(0, spec.M, spec.N), jnp.int32)
+               for _ in range(2)])
+        consts.append(system_model.sroa_constants_batched(scn, assigns))
+        args.append((scn.B_open, scn.B_open, scn.f_max, scn.p_max, scn.N0))
+    consts = jax.tree.map(lambda *x: jnp.stack(x), *consts)
+    B, b_max, f_max, p_max, N0 = (jnp.stack(a) for a in zip(*args))
+
+    def solve():
+        one = partial(sroa.solve_constants_impl, cfg=cfg)
+        inner = jax.vmap(one, in_axes=(0,) + (None,) * 6)
+        out = jax.jit(jax.vmap(inner, in_axes=(0,) * 6 + (None,)))(
+            consts, B, b_max, f_max, p_max, N0, jnp.float32(LAM))
+        return out.b, out.f, out.p, out.t, out.R
+
+    got = solve()
+    monkeypatch.setattr(sroa, "invert_rate", _rolled_invert_rate)
+    want = solve()
+    assert bool(jnp.all(jnp.isfinite(got[4])))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
 
 
 # -------------------------------------------------------------------- SROA

@@ -1,24 +1,28 @@
 """Compile rehearsal: every Pallas kernel lowers through Mosaic for a TPU v5e.
 
-Nothing runs here.  Each test compiles one raw kernel with
+Nothing runs here.  Each kernel test compiles one raw kernel with
 ``interpret=False`` for a described (not attached) ``v5e:2x2`` chip at the
 widths the planning service uses (paper cells: N=50 users, M=5 edges,
 128 cells), and asserts that the compiled program holds the kernel, by
 name, as a ``tpu_custom_call``.  It catches what interpret mode cannot:
 block shapes Mosaic refuses, unaligned slices, over-budget VMEM, removed
-Pallas APIs.
+Pallas APIs.  One more compiles the jnp route's Algorithm 2 at the engine's
+scoring shape and checks the structure of the program XLA makes of it.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import sroa
+from repro.core.system_model import SroaConstants
 from repro.kernels import (flash_attention, ops, rmsnorm, sroa_bisect,
                            topk_moves)
 
@@ -72,6 +76,63 @@ def test_sroa_solve_compiles_for_v5e(one_chip):
                                              interpret=False)
 
     assert _kernels(fn, *([u] * 7 + [s] * 5)) == {"sroa_solve"}
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line)
+    return comps
+
+
+def _count_in(comps, name, op):
+    """``op`` instructions in computation ``name`` and the fusions it calls."""
+    n = 0
+    for line in comps[name]:
+        n += len(re.findall(rf"\b{op}\(", line))
+        if " fusion(" in line:
+            n += _count_in(comps, re.search(r"calls=%([\w.-]+)", line)
+                           .group(1), op)
+    return n
+
+
+def test_algorithm2_inversion_fuses_for_v5e(one_chip):
+    """The engine's scoring of one re-search bucket (16 cells x the full
+    neighbourhood x N users, served caps): each b inversion is one device
+    loop body holding every bisection step, not a loop of one launch per
+    step."""
+    C = 16
+    cfg = sroa.SroaConfig(b_iters=30, f_iters=24)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    u = sds(C, CANDIDATES, N)
+    consts = SroaConstants(A=u, J=u, H=u, delta=u, h=u,
+                           E_cloud_total=sds(C, CANDIDATES))
+
+    def fn(consts, p, t, B, f_max, N0):
+        def alg2(c, p, t, B, f_max, N0):
+            return sroa.algorithm2(c, p, t, B, B, f_max, N0, cfg)
+        inner = jax.vmap(alg2, in_axes=(0, 0, 0, None, None, None))
+        return jax.vmap(inner)(consts, p, t, B, f_max, N0)
+
+    text = jax.jit(fn).lower(consts, u, sds(C, CANDIDATES), sds(C),
+                             sds(C, N), sds(C)).compile().as_text()
+    comps = _computations(text)
+    bodies = re.findall(r"\bwhile\(.*?body=%([\w.-]+)", text)
+    # The f bisection, the inversion inside its step, the final inversion.
+    assert len(bodies) == 3
+    inner = [b for b in bodies if _count_in(comps, b, "while") == 0]
+    assert len(inner) == 2
+    for body in inner:     # one log1p per bisection step
+        assert _count_in(comps, body, "log-plus-one") >= cfg.b_iters
 
 
 def test_sroa_bisect_vec_compiles_for_v5e(one_chip):
