@@ -20,6 +20,12 @@ Three numbers, each beside its limit (``LIMITS``):
   (a move off the current pattern, a replanned cell with no search) reads
   inf.
 
+Where the configuration has a compression ladder and the service deploys
+a level per user, both numbers price each plan at its deployed levels:
+re-pricing re-solves under them, and the search's neighbourhood is the
+joint one, whose compression moves (``KIND_COMP``) the replay follows as
+it follows descents; escapes keep the levels.
+
 ``gather`` copies the inputs and outputs of the sampled cells to the host
 while the program still holds them; ``compare`` runs the reference once the
 program's state is freed.
@@ -39,7 +45,7 @@ from bench import reference as ref
 LIMITS = {"responses_wrong": 0, "reprice_gap": 1e-3, "search_gap": 1e-3}
 REPRICE_SAMPLES = 32
 SEARCH_SAMPLES = 4
-KIND_DESCENT, KIND_ESCAPE = 0, 1     # the engine trace's move kinds
+KIND_DESCENT, KIND_ESCAPE, KIND_COMP = 0, 1, 2  # the engine trace's move kinds
 
 
 def _cell(cells, i: int) -> dict:
@@ -59,7 +65,9 @@ def _responses_wrong(run) -> int:
         if (not np.array_equal(np.asarray(resp["R"], np.float64),
                                np.asarray(plan["R"], np.float64))
                 or not np.array_equal(np.asarray(resp["assign"]),
-                                      plan["assign"])):
+                                      plan["assign"])
+                or ("comp" in plan and not np.array_equal(
+                    np.asarray(resp["comp"]), plan["comp"]))):
             wrong += 1
     return wrong
 
@@ -82,6 +90,8 @@ def gather(run) -> dict:
             "assign": p["assign"][i], "b": p["b"][i], "f": p["f"][i],
             "p": p["p"][i], "t": p["t"][i], "R": p["R"][i],
             "lam": p["lam"]})
+        if "comp" in p:
+            reprice[-1]["comp"] = p["comp"][i].astype(np.int32)
     # Every replanned cell of every tick must have had a search.
     missing = 0
     cands = []
@@ -110,36 +120,46 @@ def gather(run) -> dict:
                 "valid": np.asarray(out.trace.rounds_valid[r], bool),
                 "served": plan["assign"][i].astype(np.int32),
                 "lam": plan["lam"]})
+            if "comp" in plan:
+                # The engine starts a search without levels at level 0.
+                ic = s.get("init_comps")
+                search[-1]["init_comp"] = (
+                    np.zeros_like(search[-1]["init"]) if ic is None
+                    else np.asarray(ic[r], np.int32))
+                search[-1]["served_comp"] = plan["comp"][i].astype(np.int32)
     return {"reprice": reprice, "search": search, "missing": missing,
             "responses_wrong": _responses_wrong(run)}
 
 
 @functools.lru_cache(maxsize=None)
-def _solvers(caps: tuple):
-    """The reference's programs, in the configuration's float32."""
+def _solvers(caps: tuple, ladder: tuple | None = None):
+    """The reference's programs, in the configuration's float32.  Each takes
+    the per-user levels last: None prices without the ladder."""
     import jax
     import jax.numpy as jnp
     dt = jnp.float32
 
-    def sroa_one(cell, assign, mask, lam):
+    def sroa_one(cell, assign, mask, lam, comp):
         return ref.sroa(ref.cast(cell, dt), assign, mask,
-                        jnp.asarray(lam, dt), caps)
+                        jnp.asarray(lam, dt), caps, comp, ladder)
 
-    def claimed(cell, assign, mask, lam, b, f, p, t):
+    def claimed(cell, assign, mask, lam, b, f, p, t, comp):
         c = ref.cast(cell, dt)
-        A, J, H, delta, h, E_ct = ref.constants(c, assign, mask)
+        A, J, H, delta, h, E_ct = ref.constants(c, assign, mask, comp,
+                                                ladder)
         G = p * h / c["N0"]
         T_com = jnp.where(b > 0, H / jnp.maximum(ref._rate(b, G), 1e-30),
                           ref.BIG)
         return jnp.sum(p * T_com + A * f ** 2) + E_ct + lam * t
 
-    def nbhd(cell, assign, mask, lam):
+    def nbhd(cell, assign, mask, lam, comp):
         return ref.score_neighbourhood(ref.cast(cell, dt), assign, mask,
-                                       jnp.asarray(lam, dt), caps)
+                                       jnp.asarray(lam, dt), caps, comp,
+                                       ladder)
 
-    def score(cell, assign, mask, lam):
+    def score(cell, assign, mask, lam, comp):
         return ref.score(ref.cast(cell, dt), assign, mask,
-                         jnp.asarray(lam, dt), caps)
+                         jnp.asarray(lam, dt), caps, comp, ladder)
 
     return (jax.jit(jax.vmap(sroa_one)), jax.jit(jax.vmap(claimed)),
             jax.jit(jax.vmap(nbhd)), jax.jit(jax.vmap(score)))
@@ -153,17 +173,28 @@ def _stack_cells(items):
     return {k: np.stack([x["cell"][k] for x in items]) for k in ref.CELL_KEYS}
 
 
-def reprice_gap(items, caps: tuple) -> float:
+def _levels(items, key, ladder):
+    """The items' per-user levels, or None where the plans carry none."""
+    if key not in items[0]:
+        return None
+    if ladder is None:
+        raise ValueError("plans carry compression levels; the comparison "
+                         "needs the configuration's ladder")
+    return _stack(items, key)
+
+
+def reprice_gap(items, caps: tuple, ladder: tuple | None = None) -> float:
     if not items:
         return math.inf
-    sroa_v, claimed_v, _, _ = _solvers(caps)
+    sroa_v, claimed_v, _, _ = _solvers(caps, ladder)
     cells = _stack_cells(items)
     assign, mask = _stack(items, "assign"), _stack(items, "mask")
     lam = _stack(items, "lam").astype(np.float32)
-    R_ref = np.asarray(sroa_v(cells, assign, mask, lam)[4], np.float64)
+    comp = _levels(items, "comp", ladder)
+    R_ref = np.asarray(sroa_v(cells, assign, mask, lam, comp)[4], np.float64)
     f32 = lambda k: _stack(items, k).astype(np.float32)  # noqa: E731
     R_cl = np.asarray(claimed_v(cells, assign, mask, lam, f32("b"), f32("f"),
-                                f32("p"), f32("t")), np.float64)
+                                f32("p"), f32("t"), comp), np.float64)
     R_sv = _stack(items, "R").astype(np.float64)
     B = cells["B_edges"].astype(np.float64).sum(axis=1)
     over = (f32("b").astype(np.float64) * mask).sum(axis=1) > B * (1 + 1e-3)
@@ -172,16 +203,18 @@ def reprice_gap(items, caps: tuple) -> float:
     return float(gap.max())
 
 
-def search_gap(items, caps: tuple) -> float:
+def search_gap(items, caps: tuple, ladder: tuple | None = None) -> float:
     """Replay each sampled search's trajectory under the reference."""
     if not items:
         return 0.0
-    _, _, nbhd_v, score_v = _solvers(caps)
+    _, _, nbhd_v, score_v = _solvers(caps, ladder)
     cells = _stack_cells(items)
     mask = _stack(items, "mask")
     lam = _stack(items, "lam").astype(np.float32)
-    M = cells["gain"].shape[2]
+    N, M = cells["gain"].shape[1:]
     cur = _stack(items, "init").copy()
+    lv = _levels(items, "init_comp", ladder)
+    L = 1 if lv is None else len(ladder)
     live = np.ones(len(items), bool)
     gaps = np.zeros(len(items))
     best = np.full(len(items), np.inf)
@@ -191,7 +224,7 @@ def search_gap(items, caps: tuple) -> float:
             live[j] &= bool(it["valid"][r])
         if not live.any():
             break
-        _, R = nbhd_v(cells, cur, mask, lam)
+        _, R = nbhd_v(cells, cur, mask, lam, lv)
         R = np.asarray(R, np.float64)
         for j, it in enumerate(items):
             if not live[j]:
@@ -199,14 +232,20 @@ def search_gap(items, caps: tuple) -> float:
             lo = R[j].min()
             best[j] = min(best[j], lo)
             user, src, dst, kind, moved = (int(x) for x in it["moves"][r])
-            if moved and kind == KIND_DESCENT:
-                if cur[j, user] != src or src == dst:
+            if moved and kind in (KIND_DESCENT, KIND_COMP):
+                # Off the current pattern, or a level the ladder lacks.
+                state, n = (cur, M) if kind == KIND_DESCENT else (lv, L)
+                if (state is None or not 0 <= user < N
+                        or state[j, user] != src or src == dst
+                        or not 0 <= dst < n):
                     gaps[j] = np.inf
                     live[j] = False
                     continue
-                row = 1 + user * (M - 1) + ((dst - src) % M - 1)
+                row = 1 + user * (n - 1) + ((dst - src) % n - 1)
+                if kind == KIND_COMP:
+                    row += N * (M - 1)
                 gaps[j] = max(gaps[j], (R[j, row] - lo) / abs(lo))
-                cur[j, user] = dst
+                state[j, user] = dst
             else:
                 gaps[j] = max(gaps[j], (R[j, 0] - lo) / abs(lo))
                 if moved and kind == KIND_ESCAPE:
@@ -214,20 +253,25 @@ def search_gap(items, caps: tuple) -> float:
                 else:
                     live[j] = False
     served = _stack(items, "served")
-    R_srv = np.asarray(score_v(cells, served, mask, lam), np.float64)
+    R_srv = np.asarray(score_v(cells, served, mask, lam,
+                               _levels(items, "served_comp", ladder)),
+                       np.float64)
     final = (R_srv - best) / np.abs(best)
     gaps = np.maximum(gaps, np.where(np.isfinite(best), final, np.inf))
     gaps = np.where(np.isnan(gaps), np.inf, gaps)
     return float(gaps.max())
 
 
-def compare(g: dict, caps: tuple) -> dict:
-    """The numbers compared, each with its limit, and the verdict."""
-    search = search_gap(g["search"], caps)
+def compare(g: dict, caps: tuple, ladder: tuple | None = None) -> dict:
+    """The numbers compared, each with its limit, and the verdict.
+
+    ``ladder`` is the configuration's, one (bytes_factor, epoch_factor)
+    pair per rung; plans that carry levels are priced through it."""
+    search = search_gap(g["search"], caps, ladder)
     if g["missing"]:
         search = math.inf
     nums = {"responses_wrong": g["responses_wrong"],
-            "reprice_gap": reprice_gap(g["reprice"], caps),
+            "reprice_gap": reprice_gap(g["reprice"], caps, ladder),
             "search_gap": search}
     ok = all(nums[k] <= LIMITS[k] for k in nums)
     return {"correct": ok,

@@ -253,27 +253,89 @@ class Run:
 
 
 # ---------------------------------------------------------------- the cell
+def _made(where: str, make):
+    """``make()``, with the program's refusal of a value as a CellError that
+    names the configuration's key."""
+    try:
+        return make()
+    except (TypeError, ValueError) as e:
+        raise CellError(f"{where}: {e}") from e
+
+
+def _objects(cfg: dict, section: str, key: str, needs: tuple = ()) -> list:
+    """``cfg[section][key]``: a non-empty list of objects, each stating every
+    field in ``needs``, whose fields other than ``name`` are numbers."""
+    where = f"{section}.{key}"
+    raw = cfg[section][key]
+    if not isinstance(raw, list) or not raw:
+        raise CellError(f"{where}: a non-empty list of objects, got {raw!r}")
+    for i, e in enumerate(raw):
+        if not isinstance(e, dict):
+            raise CellError(f"{where}[{i}]: an object, got {e!r}")
+        for k in needs:
+            if k not in e:
+                raise CellError(f"{where}[{i}]: states no {k}")
+        for k, v in e.items():
+            if k != "name" and (isinstance(v, bool)
+                                or not isinstance(v, (int, float))):
+                raise CellError(f"{where}[{i}].{k}: a number, got {v!r}")
+    return raw
+
+
+# A rung states every factor, so that the program and the reference price it
+# from the file alike and neither falls back on a default of its own.
+RUNG_FIELDS = ("name", "bytes_factor", "epoch_factor")
+
+
+def _entries(cfg: dict, section: str, key: str, cls, needs=()) -> tuple:
+    """``cfg[section][key]`` as a tuple of the program's ``cls``."""
+    return tuple(_made(f"{section}.{key}[{i}]", lambda e=e: cls(**e))
+                 for i, e in enumerate(_objects(cfg, section, key, needs)))
+
+
+def ladder_factors(cfg: dict) -> tuple | None:
+    """The configuration's compression ladder as the reference takes it: one
+    (bytes_factor, epoch_factor) pair per rung; None without a ladder."""
+    if "ladder" not in cfg["service"]:
+        return None
+    return tuple((float(e["bytes_factor"]), float(e["epoch_factor"]))
+                 for e in _objects(cfg, "service", "ladder", RUNG_FIELDS))
+
+
 def build(cell: Cell, devices):
     """The cell's ``PlanningService``, bootstrapped.
 
     The world (the fleet's draw and its dynamics) comes from the
     configuration's ``world_seed``, so every run does the same work; a run's
-    ``--seed`` draws its request stream and the comparison's sample."""
+    ``--seed`` draws its request stream and the comparison's sample.
+
+    ``scenario.tiers`` lists device tiers (``DeviceTier``'s fields) and
+    ``service.ladder`` compression rungs (``CompressionLevel``'s fields, all
+    stated, rung 0 the identity); a malformed entry raises CellError."""
     from repro.core import sroa
-    from repro.core.wireless import ScenarioSpec
+    from repro.core.wireless import DeviceTier, ScenarioSpec
+    from repro.fed.compression import CompressionLadder, CompressionLevel
     from repro.fleet import draw_fleet, dynamics
     from repro.fleet.service import DriftConfig, PlanningService, ServiceConfig
 
     cfg, tr = cell.config, cell.traffic
     scn = {k: tuple(v) if isinstance(v, list) else v
            for k, v in cfg["scenario"].items()}
-    spec = ScenarioSpec(**scn)
+    if "tiers" in scn:
+        scn["tiers"] = _entries(cfg, "scenario", "tiers", DeviceTier)
+    spec = _made("scenario", lambda: ScenarioSpec(**scn))
     seed = int(cfg["world_seed"])
     fleet = draw_fleet(seed, cfg["cells"], spec,
                        n_range=(cfg["users_min"], spec.N))
     stream = dynamics.StreamConfig(side_m=spec.side_m, **tr["stream"])
+    svc = dict(cfg["service"])
+    if "ladder" in svc:
+        levels = _entries(cfg, "service", "ladder", CompressionLevel,
+                          RUNG_FIELDS)
+        svc["ladder"] = _made("service.ladder",
+                              lambda: CompressionLadder(levels=levels))
     svc_cfg = ServiceConfig(drift=DriftConfig(**cfg["drift"]), stream=stream,
-                            event_rate=tr["event_rate"], **cfg["service"])
+                            event_rate=tr["event_rate"], **svc)
     return PlanningService(fleet, lam=cfg["lam"],
                            sroa_cfg=sroa.SroaConfig(**cfg["sroa"]),
                            cfg=svc_cfg, spec=spec, seed=seed,
@@ -305,7 +367,11 @@ def warm(service, share: float) -> None:
     for b in sizes:
         idx = np.arange(b) % C
         sub = jax.tree.map(lambda x, i=idx: x[jnp.asarray(i)], service.fleet)
-        out = service._engine(sub, jnp.asarray(service.assigns[idx]), rows=idx)
+        # A re-search with the ladder on starts from the deployed levels.
+        kw = ({"init_comps": jnp.asarray(service.comps[idx], jnp.int32)}
+              if service._comp_on else {})
+        out = service._engine(sub, jnp.asarray(service.assigns[idx]),
+                              rows=idx, **kw)
         jax.block_until_ready((out.assign, fbatch.fleet_assignments(sub)))
 
 
@@ -316,19 +382,24 @@ def capture_searches(service, sink: list) -> None:
     def kept(fleet, init_assigns, *args, **kwargs):
         out = engine(fleet, init_assigns, *args, **kwargs)
         sink.append({"fleet": fleet, "init": init_assigns,
-                     "rows": kwargs.get("rows"), "out": out})
+                     "rows": kwargs.get("rows"),
+                     "init_comps": kwargs.get("init_comps"), "out": out})
         return out
     service._engine = kept
 
 
 def plan_table(service) -> dict:
-    """The deployed plans after a tick (host copies, device refs for cells)."""
+    """The deployed plans after a tick (host copies, device refs for cells);
+    with the ladder on, the deployed levels under ``comp``."""
     a = service.alloc
-    return {"tick": service.tick_idx - 1, "fleet": service.fleet,
+    plan = {"tick": service.tick_idx - 1, "fleet": service.fleet,
             "active": np.asarray(service.state.active, bool).copy(),
             "assign": service.assigns.copy(),
             "b": a.b, "f": a.f, "p": a.p, "t": a.t, "R": a.R,
             "lam": service.lam}
+    if service._comp_on:
+        plan["comp"] = service.comps.copy()
+    return plan
 
 
 def poisson_offsets(rate: float, seconds: float, seed: int) -> np.ndarray:

@@ -1,6 +1,14 @@
 """Plain reference of the planning path: the paper's cost model (eqs 4-15),
 SROA (Algorithms 2-4) and one round of the single-move neighbourhood search.
 
+A configuration with a compression ladder (DESIGN.md D11) adds a per-user
+level ``comp`` (N,) and the ladder's factors, ``ladder`` = one
+(bytes_factor, epoch_factor) pair per rung, passed like ``caps``: a user's
+cycles a sample are ``c * cycle_mult * epoch_factor[comp]`` and its upload
+``s_bits * size_mult * bytes_factor[comp]``, and the neighbourhood gains the
+rows that change one user's level.  Without ``comp`` every function runs
+the ladder-free code.
+
 Written in straightforward ``jax.numpy`` against plain arrays, with no
 kernels and no batching tricks, and importing nothing of the program: a cell
 is a dict of the scenario's arrays (``CELL_KEYS``).  ``dtype`` selects the
@@ -40,7 +48,19 @@ def _cloud(cell):
     return T, cell["p_edge"] * T
 
 
-def constants(cell, assign, mask):
+def loads(cell, comp=None, ladder=None):
+    """Per-user cycles a sample and upload bits: the device tier's
+    multipliers and, with ``comp``, the deployed rung's factors."""
+    c = cell["c"] * cell["cycle_mult"]
+    s = cell["s_bits"] * cell["size_mult"]
+    if comp is not None:
+        dt = c.dtype
+        c = c * jnp.asarray([e for _, e in ladder], dt)[comp]
+        s = s * jnp.asarray([b for b, _ in ladder], dt)[comp]
+    return c, s
+
+
+def constants(cell, assign, mask, comp=None, ladder=None):
     """Per-user constants of problem (17): A, J, H, delta, h, E_cloud."""
     M = cell["gain"].shape[1]
     dt = cell["gain"].dtype
@@ -50,8 +70,7 @@ def constants(cell, assign, mask):
     T_cl = jnp.where(occ, T_cl, 0.0)
     E_cl = jnp.where(occ, E_cl, 0.0)
     IKL = cell["I"] * cell["K"] * cell["L"]
-    c = cell["c"] * cell["cycle_mult"]
-    s = cell["s_bits"] * cell["size_mult"]
+    c, s = loads(cell, comp, ladder)
     A = 0.5 * cell["alpha"] * IKL * c * cell["D"]
     J = IKL * c * cell["D"]
     H = cell["I"] * cell["K"] * s * jnp.ones_like(c)
@@ -82,13 +101,13 @@ def _invert(G, target, b_max, iters):
     return jnp.where(feas, hi, b_max)
 
 
-def sroa(cell, assign, mask, lam, caps: tuple):
+def sroa(cell, assign, mask, lam, caps: tuple, comp=None, ladder=None):
     """Algorithm 4 (with 2 and 3 nested) for one assignment.
 
     Returns (b, f, p, t, R, b_sum) with R = E_sum + lam * t.
     """
     tol = _tol(caps)
-    A, J, H, delta, h, E_ct = constants(cell, assign, mask)
+    A, J, H, delta, h, E_ct = constants(cell, assign, mask, comp, ladder)
     B = jnp.sum(cell["B_edges"])
     f_max, p_max, N0 = cell["f_max"], cell["p_max"], cell["N0"]
     dt = h.dtype
@@ -199,14 +218,13 @@ def sroa(cell, assign, mask, lam, caps: tuple):
     return best
 
 
-def evaluate(cell, assign, b, f, p, lam, mask):
+def evaluate(cell, assign, b, f, p, lam, mask, comp=None, ladder=None):
     """Eq 15 objective R and the per-edge costs R_m (eq 23) of a plan."""
     M = cell["gain"].shape[1]
     dt = cell["gain"].dtype
     psi = jax.nn.one_hot(assign, M, dtype=dt) * mask.astype(dt)[:, None]
     h = jnp.sum(psi * cell["gain"], axis=1)
-    c = cell["c"] * cell["cycle_mult"]
-    s = cell["s_bits"] * cell["size_mult"]
+    c, s = loads(cell, comp, ladder)
     T_cmp = cell["L"] * c * cell["D"] / jnp.maximum(f, 1.0)
     E_cmp = 0.5 * cell["alpha"] * cell["L"] * f ** 2 * c * cell["D"]
     bs = jnp.maximum(b, 1e-9)
@@ -239,17 +257,44 @@ def neighbourhood(assign, mask, M: int):
     return cands, valid
 
 
-def score(cell, assign, mask, lam, caps: tuple):
-    """Eq 15 R of an assignment with its SROA allocation."""
-    b, f, p, _, _, _ = sroa(cell, assign, mask, lam, caps)
-    return evaluate(cell, assign, b, f, p, lam, mask)[0]
-
-
-def score_neighbourhood(cell, assign, mask, lam, caps: tuple):
-    """R of every single-move neighbour (invalid rows -> +inf)."""
-    M = cell["gain"].shape[1]
+def joint_neighbourhood(assign, comp, mask, M: int, L: int):
+    """The single moves, each keeping every level, then every movable user
+    to each other rung (cyclically: its level + 1, ..., + L-1 mod L) on
+    the same assignment.  Returns (cands, comps, valid), 1+N(M-1)+N(L-1)
+    rows."""
+    N = assign.shape[0]
+    comp, mask = jnp.asarray(comp), jnp.asarray(mask)
     cands, valid = neighbourhood(assign, mask, M)
-    R = jax.vmap(lambda a: score(cell, a, mask, lam, caps))(cands)
+    users = jnp.repeat(jnp.arange(N), L - 1)
+    lv = (comp[users] + jnp.tile(jnp.arange(1, L), N)) % L
+    bumps = jnp.tile(comp[None, :], (N * (L - 1), 1))
+    bumps = bumps.at[jnp.arange(N * (L - 1)), users].set(lv)
+    cands = jnp.concatenate([cands, jnp.tile(assign[None, :],
+                                             (N * (L - 1), 1))])
+    comps = jnp.concatenate([jnp.tile(comp[None, :], (1 + N * (M - 1), 1)),
+                             bumps])
+    return cands, comps, jnp.concatenate([valid, mask[users]])
+
+
+def score(cell, assign, mask, lam, caps: tuple, comp=None, ladder=None):
+    """Eq 15 R of an assignment with its SROA allocation."""
+    b, f, p, _, _, _ = sroa(cell, assign, mask, lam, caps, comp, ladder)
+    return evaluate(cell, assign, b, f, p, lam, mask, comp, ladder)[0]
+
+
+def score_neighbourhood(cell, assign, mask, lam, caps: tuple, comp=None,
+                        ladder=None):
+    """R of every single-move neighbour (invalid rows -> +inf); with
+    ``comp``, of the joint neighbourhood."""
+    M = cell["gain"].shape[1]
+    if comp is None:
+        cands, valid = neighbourhood(assign, mask, M)
+        R = jax.vmap(lambda a: score(cell, a, mask, lam, caps))(cands)
+    else:
+        cands, comps, valid = joint_neighbourhood(assign, comp, mask, M,
+                                                  len(ladder))
+        R = jax.vmap(lambda a, cp: score(cell, a, mask, lam, caps, cp,
+                                         ladder))(cands, comps)
     return cands, jnp.where(valid, R, jnp.inf)
 
 

@@ -84,7 +84,8 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices,
     gc.collect()
     sroa = cell.config["sroa"]
     verdict = check.compare(gathered, (sroa["b_iters"], sroa["f_iters"],
-                                       sroa["p_iters"], sroa["t_iters"]))
+                                       sroa["p_iters"], sroa["t_iters"]),
+                            harness.ladder_factors(cell.config))
 
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):

@@ -4,7 +4,7 @@ bfloat16 control in the program's place, come out not correct.
 
 Each run skips the harness's look for a chip and drives the rest of a run
 (``run_cell.execute``) on a 2-cell fleet of 8 users and 3 edges."""
-import copy
+import json
 import sys
 from pathlib import Path
 
@@ -21,8 +21,10 @@ SECONDS = 1.5
 
 
 def _tiny_cell():
-    cell = harness.resolve("metro.churn")
-    cfg = copy.deepcopy(cell.config)
+    """``paper-metro`` under ``pedestrian-churn`` (``m8.churn``'s traffic),
+    cut to the tiny size."""
+    cell = harness.resolve("m8.churn")
+    cfg = json.loads((ROOT / "bench/configs/paper-metro.json").read_text())
     cfg.update(cells=2, users_min=6)
     cfg["scenario"].update(N=8, M=3)
     cfg["sroa"] = {"b_iters": 12, "f_iters": 8, "p_iters": 6, "t_iters": 8}

@@ -78,7 +78,7 @@ def test_new_cell_config_traffic_and_metric_are_files_plus_entries(tmp_path):
     assert "dummy.ticks" in [m["name"] for m in cell.per_layer]
     read = harness.reader("dummy.ticks", root=tmp_path)
     assert read(SimpleNamespace(ticks=[1, 2, 3])) == 3
-    other = harness.resolve("metro.churn", root=tmp_path)
+    other = harness.resolve("m8.churn", root=tmp_path)
     assert "dummy.ticks" not in [m["name"] for m in other.per_layer]
     for p, data in before.items():
         assert p.read_bytes() == data, p
@@ -88,7 +88,7 @@ def test_new_cell_config_traffic_and_metric_are_files_plus_entries(tmp_path):
 def test_run_cell_refuses_cpu(capsys):
     import jax
     assert jax.devices()[0].platform == "cpu"
-    rc = run_cell.main(["--workload", "metro.churn", "--seed", "1",
+    rc = run_cell.main(["--workload", "m8.churn", "--seed", "1",
                         "--seconds", "1"])
     out = capsys.readouterr()
     assert rc != 0
