@@ -224,7 +224,9 @@ class PlanningService:
         """One engine call re-searching the drifted cells (bucket-padded).
 
         Counts the batched loop's trip (its slowest row's rounds), the
-        rounds of the real rows, the bucket's rows and cells, and escapes.
+        rounds of the real rows, the bucket's rows and cells, and escapes;
+        with the ladder on, also the real rows' accepted moves and those
+        of them that changed a level.
         """
         tel = self.telemetry
         k = idx.size
@@ -258,10 +260,24 @@ class PlanningService:
         with tel.span("svc.research.engine"):
             out = self._engine(sub, init, rows=pidx, init_comps=icomp,
                                tail_inits=tails)
-            assign = np.asarray(out.assign)
+            if self._comp_on:
+                # The levels and the trace's moves come back in the
+                # assignment's one fetch.
+                assign, comp, moves = jax.device_get(
+                    (out.assign, out.comp, out.trace.moves))
+            else:
+                assign = np.asarray(out.assign)
         with tel.span("svc.research.scatter"):
             self.assigns[idx] = assign[:k]
-            self.comps[idx] = np.asarray(out.comp)[:k]
+            if self._comp_on:
+                self.comps[idx] = comp[:k]
+                # Accepted moves of the real rows (padding repeats idx[0]),
+                # and those that changed a level.
+                moved = moves[:k, :, 4] == 1
+                tel.count("research.moves", moved.sum())
+                tel.count("research.comp_moves",
+                          (moved & (moves[:k, :, 3] == fengine.KIND_COMP))
+                          .sum())
             if self._tail is not None:
                 self._tail[idx] = assign[:k]
             rounds = np.asarray(out.rounds)
@@ -376,6 +392,10 @@ class PlanningService:
                     self.R_ref[idx] = R_now[idx]
                     self._install_cache(idx)
                     tel.count("install.cells", idx.size)
+                if self._comp_on:
+                    live = np.asarray(self.state.active, bool)
+                    tel.count("install.compressed",
+                              (self.comps[live] != 0).sum())
                 sum_R = float(R_now.sum())
 
             with tel.span("svc.respond"):
@@ -393,6 +413,15 @@ class PlanningService:
                     "drift_channel": report.channel.tolist(),
                     "plan_ms": tick_ms,
                 }
+                if self._comp_on:
+                    # The slots that hold a user, so that ``comp`` reads as
+                    # the active users' levels, and the accepted moves of
+                    # the tick's re-search (its counters).
+                    got = tel.last_tick.counters
+                    base["active"] = live.tolist()
+                    base["moves"] = {
+                        "all": got.get("research.moves", 0),
+                        "comp": got.get("research.comp_moves", 0)}
                 served = 0
                 coalesced = 0
                 for reqs in groups.values():

@@ -510,3 +510,66 @@ def test_snapshot_exports_spans_and_counters_and_roundtrips():
     snap = svc.telemetry.snapshot()
     assert snap["spans"] == {} and snap["counters"] == {}
     assert snap["setup_ms"] == {"svc.bootstrap": boot}
+
+
+@pytest.mark.parametrize("ladder_on", [True, False])
+def test_compression_counters_read_the_engine_trace(ladder_on):
+    """With the ladder on, a re-search counts its real rows' accepted moves
+    and those of them that changed a level (bucket padding is not counted),
+    the install counts the active users at a level other than 0, and each
+    response carries the active slots and the tick's move counts.  With it
+    off, none of these is counted or served."""
+    from repro.fed import compression as comp_lib
+    names = {"research.moves", "research.comp_moves", "install.compressed"}
+    svc = make_service(replan_all=True,
+                       ladder=(comp_lib.default_ladder() if ladder_on
+                               else None))
+    outs = []
+    engine = svc._engine
+
+    def kept(*a, **k):
+        outs.append(engine(*a, **k))
+        return outs[-1]
+    svc._engine = kept
+
+    def counted(moves):
+        moved = moves[..., 4] == 1
+        return {"all": int(moved.sum()),
+                "comp": int((moved & (moves[..., 3] == fengine.KIND_COMP))
+                            .sum())}
+
+    idx = np.array([2, 0, 3])                  # 3 cells -> a bucket of 4
+    with svc.telemetry.tick() as tick:
+        svc._replan(idx, None)
+    if ladder_on:
+        moves = np.asarray(outs[-1].trace.moves)
+        assert moves.shape[0] == 4
+        want = counted(moves[:3])
+        assert tick.counters["research.moves"] == want["all"]
+        assert tick.counters["research.comp_moves"] == want["comp"]
+    else:
+        assert not names & set(tick.counters)
+
+    comp_moves = 0
+    for _ in range(3):
+        h = svc.submit()
+        n = len(outs)
+        rec = svc.tick()
+        resp = h.result(0)
+        got = svc.telemetry.last_tick.counters
+        if not ladder_on:
+            assert not names & set(got)
+            assert "active" not in resp and "moves" not in resp
+            continue
+        k = rec.replanned.size
+        want = counted(np.concatenate(
+            [np.asarray(o.trace.moves)[:k] for o in outs[n:]]))
+        assert resp["moves"] == {"all": got.get("research.moves", 0),
+                                 "comp": got.get("research.comp_moves", 0)}
+        assert resp["moves"] == want
+        active = np.asarray(svc.state.active, bool)
+        assert resp["active"] == active.tolist()
+        assert got["install.compressed"] == (svc.comps[active] != 0).sum()
+        comp_moves += want["comp"]
+    if ladder_on:
+        assert comp_moves + tick.counters["research.comp_moves"] > 0
