@@ -19,14 +19,25 @@ The innermost bandwidth inversion is the compute hot-spot when planning for
 fleet-scale N (the paper's complexity analysis §IV-C is dominated by it).
 :func:`invert_rate` unrolls its fixed-trip bisection into the body of a
 one-trip device loop, so XLA fuses all its steps into one op per call
-rather than launching one op per step; ``repro.kernels.sroa_bisect``
-provides a Pallas TPU kernel for it, validated against :func:`invert_rate`
-(the pure-jnp oracle) in tests.
+rather than launching one op per step.  The op is compute-bound, so its
+cost is the number of (8, 128) vector tiles it runs over.  Batched as the
+assignment engine batches it, (cells, candidates, N = 50 users), the users
+sit on the 128-wide lane axis and fill 38% of each tile; so under ``vmap``
+Algorithm 2's f search (:func:`_alg2_search`) runs the bisection on one
+lane-dense ``(rows, 128)`` stream of the whole batch instead, wherever
+that at least halves the tiles (:func:`invert_tiles`), streaming G and
+b_max once per search and each step's target in and b out.  Every
+element gets the same ops either way, so the results are bitwise the
+same.
+``repro.kernels.sroa_bisect`` provides a Pallas TPU kernel for the
+inversion, validated against :func:`invert_rate` (the pure-jnp oracle) in
+tests.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -109,6 +120,57 @@ def invert_rate(G: jnp.ndarray, target: jnp.ndarray, b_max,
     _, hi = lax.while_loop(lambda carry: carry[0], bisect,
                            (True, jnp.full_like(G, b_max)))
     return jnp.where(feas, hi, b_max)
+
+
+# Lane-dense inversion under vmap.  A tile is one (8, 128) f32 vreg.
+TILE = 8 * 128
+# Fewest tiles a lane-dense stream must fill before the relayout into it
+# and back pays.  Not set from a measurement: every engine bucket the
+# benchmark's cells run fills 40 or more tiles and the re-pricing 1-2, so
+# any floor from 3 to 40 splits them the same way.
+_DENSE_MIN_TILES = 16
+
+
+def invert_tiles(shape) -> tuple[int, int, bool]:
+    """How a batched inversion of ``shape`` = (..., S, N) runs.
+
+    Returns (elements, tiles, dense): the tiles are (8, 128) vregs, of the
+    batch as laid out (N on the lanes, S on the sublanes) or, where that at
+    least halves them and fills at least ``_DENSE_MIN_TILES``, of one
+    lane-dense stream of every element (``dense``).
+    """
+    elements = math.prod(shape)
+    tiled = (math.prod(shape[:-2]) * -(-shape[-2] // 8)
+             * -(-shape[-1] // 128))
+    dense = -(-elements // TILE)
+    if dense >= _DENSE_MIN_TILES and 2 * dense <= tiled:
+        return elements, dense, True
+    return elements, tiled, False
+
+
+def _dense_inverter(G, b_max, iters: int):
+    """``target -> invert_rate(G, target, b_max)`` over a batch, G (..., N)
+    and b_max (...), run on one lane-dense ``(rows, 128)`` stream of every
+    element, padded to whole tiles (G = 1, target = 0, b_max = 1, which
+    bisect harmlessly).  G and b_max are streamed here, once; each call
+    streams its target in and its b back out."""
+    n = G.size
+    rows = -(-n // TILE) * 8
+
+    def stream(x, fill):
+        return jnp.pad(x.reshape(-1), (0, rows * 128 - n),
+                       constant_values=fill).reshape(rows, 128)
+
+    if b_max.ndim < G.ndim:                  # one b_max per problem
+        b_max = b_max[..., None]
+    Gs = stream(G, 1.0)
+    bms = stream(jnp.broadcast_to(b_max, G.shape), 1.0)
+
+    def invert(target):
+        b = invert_rate(Gs, stream(target, 0.0), bms, iters)
+        return b.reshape(-1)[:n].reshape(G.shape)
+
+    return invert
 
 
 @functools.lru_cache(maxsize=None)
@@ -230,33 +292,17 @@ def _solve_constants_fused(consts: SroaConstants, B, b_max, f_max, p_max,
     return SroaResult(b=b, f=f, p=p, t=t, R=R, b_sum=b_sum, feasible=feas)
 
 
-def _invert_rate_dispatch(G, target, b_max, iters, use_pallas: bool):
-    if use_pallas:
-        return _pallas_invert(iters)(G, target, jnp.asarray(b_max,
-                                                            jnp.float32))
-    return invert_rate(G, target, b_max, iters=iters)
-
-
 # --------------------------------------------------------------------------
 # Algorithm 2: optimal (b, f) with fixed (p, t)
 # --------------------------------------------------------------------------
-@jax.named_scope("sroa.alg2")
-def algorithm2(consts: SroaConstants, p: jnp.ndarray, t, B, b_max,
-               f_max: jnp.ndarray, N0, cfg: SroaConfig):
-    """Returns (b, f, b_sum). Lockstep bisection on f, inner inversion for b."""
-    G = p * consts.h / N0
-    # Lemma 1 lower bound: f >= J / (t - delta - ln2 * H / G); guard the
-    # degenerate case (denominator <= 0 -> infeasible even at b -> inf).
-    denom = t - consts.delta - LN2 * consts.H / jnp.maximum(G, 1e-30)
-    f_lo0 = jnp.where(denom > 0, consts.J / jnp.maximum(denom, 1e-30), f_max)
-    f_lo0 = jnp.clip(f_lo0, 0.0, f_max)
-    f_hi0 = f_max
-
+def _alg2_bisect(invert, t, delta, J, H, f_lo0, f_hi0, B,
+                 cfg: SroaConfig):
+    """Algorithm 2's lockstep bisection on f for one problem, with
+    ``invert(target) -> b`` the inner inversion.  Returns (b, f)."""
     def b_of_f(f):
-        tau = t - consts.delta - consts.J / jnp.maximum(f, 1.0)
-        target = jnp.where(tau > 0, consts.H / jnp.maximum(tau, 1e-30), _BIG)
-        return _invert_rate_dispatch(G, target, b_max, cfg.b_iters,
-                                     cfg.use_pallas)
+        tau = t - delta - J / jnp.maximum(f, 1.0)
+        target = jnp.where(tau > 0, H / jnp.maximum(tau, 1e-30), _BIG)
+        return invert(target)
 
     def cond(carry):
         f_lo, f_hi, it = carry
@@ -274,7 +320,107 @@ def algorithm2(consts: SroaConstants, p: jnp.ndarray, t, B, b_max,
 
     f_lo, f_hi, _ = lax.while_loop(cond, body, (f_lo0, f_hi0, 0))
     f = f_hi                          # feasible side (b_sum <= B when any f is)
-    b = b_of_f(f)
+    return b_of_f(f), f
+
+
+def _alg2_bisect_dense(G, b_max, t, delta, J, H, f_lo0, f_hi0, B,
+                       cfg: SroaConfig):
+    """:func:`_alg2_bisect` over a batch of problems, as ``vmap`` runs it
+    (the loop steps while any problem steps, and a problem that has
+    stopped keeps its carry), with the inversion on lane-dense tiles
+    (:func:`_dense_inverter`).  Per user (..., N): G, delta, J, H, f_lo0,
+    f_hi0; per problem (...): b_max, t, B."""
+    invert = _dense_inverter(G, b_max, cfg.b_iters)
+    t = t[..., None]
+
+    def b_of_f(f):
+        tau = t - delta - J / jnp.maximum(f, 1.0)
+        target = jnp.where(tau > 0, H / jnp.maximum(tau, 1e-30), _BIG)
+        return invert(target)
+
+    def stepping(carry):
+        f_lo, f_hi, it = carry
+        gap = jnp.max((f_hi - f_lo) / jnp.maximum(f_hi, 1.0), axis=-1)
+        return jnp.logical_and(gap > cfg.eps0, it < cfg.f_iters)
+
+    def body(carry):
+        f_lo, f_hi, it = carry
+        on = stepping(carry)
+        f = 0.5 * (f_lo + f_hi)
+        b_sum = jnp.sum(b_of_f(f), axis=-1)
+        spare = (b_sum < B)[..., None]
+        step = on[..., None]
+        return (jnp.where(step, jnp.where(spare, f_lo, f), f_lo),
+                jnp.where(step, jnp.where(spare, f, f_hi), f_hi),
+                jnp.where(on, it + 1, it))
+
+    it0 = jnp.zeros(G.shape[:-1], jnp.int32)
+    f_lo, f_hi, _ = lax.while_loop(lambda c: jnp.any(stepping(c)), body,
+                                   (f_lo0, f_hi0, it0))
+    return b_of_f(f_hi), f_hi
+
+
+@functools.lru_cache(maxsize=None)
+def _alg2_search(cfg: SroaConfig):
+    """Algorithm 2's f search on the jnp inversion, with a batching rule
+    that runs a vmapped search on lane-dense tiles.
+
+    Unbatched, G is (N,) and this is :func:`_alg2_bisect` on
+    :func:`invert_rate`.  Every vmap level re-enters the rule one rank
+    higher, so under the engine's cells x candidates the whole batch
+    arrives at once; where :func:`invert_tiles` says so it runs as
+    :func:`_alg2_bisect_dense`, with G and b_max streamed once for the
+    whole search, else as the plain search vmapped.  Every element gets
+    the same ops either way, so the results are bitwise the same.
+    """
+    from jax.custom_batching import custom_vmap
+
+    def plain(G, b_max, t, delta, J, H, f_lo0, f_hi0, B):
+        def invert(target):
+            return invert_rate(G, target, b_max, iters=cfg.b_iters)
+        return _alg2_bisect(invert, t, delta, J, H, f_lo0, f_hi0, B, cfg)
+
+    @custom_vmap
+    def search(G, *args):
+        if G.ndim > 1 and invert_tiles(G.shape)[2]:
+            return _alg2_bisect_dense(G, *args, cfg)
+        fn = plain
+        for _ in range(G.ndim - 1):
+            fn = jax.vmap(fn)
+        return fn(G, *args)
+
+    @search.def_vmap
+    def _rule(axis_size, in_batched, *args):  # noqa: ANN001
+        args = tuple(
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip(args, in_batched))
+        return search(*args), (True, True)
+
+    return search
+
+
+@jax.named_scope("sroa.alg2")
+def algorithm2(consts: SroaConstants, p: jnp.ndarray, t, B, b_max,
+               f_max: jnp.ndarray, N0, cfg: SroaConfig):
+    """Returns (b, f, b_sum). Lockstep bisection on f, inner inversion for b."""
+    G = p * consts.h / N0
+    # Lemma 1 lower bound: f >= J / (t - delta - ln2 * H / G); guard the
+    # degenerate case (denominator <= 0 -> infeasible even at b -> inf).
+    denom = t - consts.delta - LN2 * consts.H / jnp.maximum(G, 1e-30)
+    f_lo0 = jnp.where(denom > 0, consts.J / jnp.maximum(denom, 1e-30), f_max)
+    f_lo0 = jnp.clip(f_lo0, 0.0, f_max)
+    f_hi0 = f_max
+    b_max = jnp.asarray(b_max, jnp.float32)
+    if cfg.use_pallas:
+        def invert(target):
+            return _pallas_invert(cfg.b_iters)(G, target, b_max)
+        b, f = _alg2_bisect(invert, t, consts.delta, consts.J, consts.H,
+                            f_lo0, f_hi0, B, cfg)
+    else:
+        f32 = partial(jnp.asarray, dtype=jnp.float32)
+        b, f = _alg2_search(cfg)(G, b_max, f32(t), consts.delta, consts.J,
+                                 consts.H, f_lo0, jnp.broadcast_to(
+                                     f_hi0, G.shape), f32(B))
     return b, f, jnp.sum(b)
 
 

@@ -748,6 +748,25 @@ def search_core(scn: Scenario, init_assign: jnp.ndarray, mask: jnp.ndarray,
     return jax.tree.map(lambda x: x[i], res)
 
 
+def inversion_batches(rows: int, N: int, M: int, top_k: int = 0,
+                      n_starts: int = 1, tail: bool = False,
+                      horizon: int = 1, ladder=None) -> list[tuple]:
+    """Batch shapes of SROA's inversions in one fleet engine call over
+    ``rows`` cells (see ``sroa.invert_tiles``): the scoring's
+    (rows, [starts], [slots], A, N), A the candidates a round scores, and
+    the final solve's (rows, [starts], N)."""
+    levels = len(ladder) if _comp_enabled(ladder) else 1
+    if top_k > 0:
+        A = 1 + (5 if levels > 1 else 1) * top_k
+    else:
+        A = 1 + N * (M - 1) + N * (levels - 1)
+    lead = (rows,)
+    if n_starts > 1 or tail:
+        lead += (max(n_starts, 1) + int(tail),)
+    slots = (horizon,) if horizon > 1 else ()
+    return [lead + slots + (A, N), lead + (N,)]
+
+
 @partial(jax.jit, static_argnames=("cfg", "max_rounds", "escape_iters",
                                    "top_k", "n_starts", "switch_cost",
                                    "ladder"))

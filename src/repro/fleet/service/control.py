@@ -224,7 +224,8 @@ class PlanningService:
         """One engine call re-searching the drifted cells (bucket-padded).
 
         Counts the batched loop's trip (its slowest row's rounds), the
-        rounds of the real rows, the bucket's rows and cells, and escapes;
+        rounds of the real rows, the bucket's rows and cells, escapes, and
+        the elements and tile lanes of the program's batched inversions;
         with the ladder on, also the real rows' accepted moves and those
         of them that changed a level.
         """
@@ -286,6 +287,19 @@ class PlanningService:
             tel.count("research.rows", pidx.size)
             tel.count("research.cells", k)
             tel.count("research.escapes", np.asarray(out.escapes)[:k].sum())
+            # The tile fill of the program's SROA inversions
+            # (research.invert_fill = elements / lanes), from its shapes:
+            # each device runs its share of the rows.
+            ndev = 1 if self.mesh is None else self.mesh.devices.size
+            for shape in fengine.inversion_batches(
+                    -(-pidx.size // ndev), self.fleet.N_max, self.fleet.M,
+                    self.cfg.top_k, self.cfg.n_starts, tails is not None,
+                    self.cfg.horizon if self._horizon_mode() else 1,
+                    self.ladder):
+                elements, tiles, _ = sroa.invert_tiles(shape)
+                tel.count("research.invert_elements", ndev * elements)
+                tel.count("research.invert_lanes",
+                          ndev * tiles * sroa.TILE)
 
     # ------------------------------------------------------------- topology
     def _redesign_topology(self) -> int:

@@ -1,4 +1,5 @@
 """Unit + property tests for the paper's cost model and SROA (Algs 2-4)."""
+import re
 from functools import partial
 
 import jax
@@ -119,13 +120,16 @@ def _bits(x):
     return np.asarray(x, np.float32).view(np.uint32)
 
 
-def _inversion_case(kind, shape, seed=0):
+def _inversion_case(kind, shape, seed=0, shared_b_max=False):
     """(G, target, b_max, b_max broadcast to ``shape``) for one edge of the
-    inversion; b_max is a scalar, or one per cell for a (C, A, N) shape."""
+    inversion; b_max is a scalar, or one per cell for a (C, A, N) shape
+    (the same in every cell with ``shared_b_max``)."""
     rng = np.random.default_rng(seed)
     G = (10.0 ** rng.uniform(2.0, 10.0, shape)).astype(np.float32)
     cells = shape[:1] if len(shape) > 1 else ()       # one b_max per cell
     b_max = (10.0 ** rng.uniform(5.0, 7.0, cells)).astype(np.float32)
+    if shared_b_max:
+        b_max = np.full_like(b_max, b_max.flat[0])
     bm = np.reshape(b_max, cells + (1,) * (len(shape) - len(cells)))
     sup = bm * np.log1p(G / bm) / np.log(2.0)
     target = {
@@ -193,6 +197,126 @@ def test_solve_unrolled_inversion_bitwise_rolled(monkeypatch):
     assert bool(jnp.all(jnp.isfinite(got[4])))
     for g, w in zip(got, want):
         np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+def _inversion_loops(text):
+    """Shapes of the inversion's one-trip loops in a lowered program (each
+    carries G, target, its flag and the upper bound)."""
+    return re.findall(r"stablehlo\.while\(.*\) : tensor<([\dx]+)xf32>, "
+                      r"tensor<\1xf32>, tensor<i1>, tensor<\1xf32>$", text,
+                      re.M)
+
+
+@pytest.mark.parametrize("b_max_kind", ["scalar", "per_cell"])
+@pytest.mark.parametrize("shape", [(3, 21, 50), (2, 351, 50)])
+@pytest.mark.parametrize("kind", ["reachable", "unreachable", "big", "zero"])
+def test_lane_dense_inversion_bitwise_vmapped(kind, shape, b_max_kind):
+    """The inversion run as one lane-dense stream of a (cells, candidates,
+    N) batch, padded to whole tiles (3150 and 35100 elements are not whole
+    tiles), is bitwise the inversion vmapped as laid out."""
+    scalar = b_max_kind == "scalar"
+    G, target, b_max, b_max_full = _inversion_case(kind, shape,
+                                                   shared_b_max=scalar)
+    if scalar:
+        b_max = b_max[0]
+    outer = None if scalar else 0
+
+    @jax.jit
+    def dense(G, target, b_max):
+        per_problem = jnp.broadcast_to(
+            b_max if scalar else b_max[:, None], shape[:-1])
+        return sroa._dense_inverter(G, per_problem, 30)(target)
+
+    got = dense(G, target, b_max)
+    rows = -(-G.size // 1024) * 8
+    assert _inversion_loops(dense.lower(G, target, b_max).as_text()) == [
+        f"{rows}x128"]
+    inner = jax.vmap(partial(sroa.invert_rate, iters=30),
+                     in_axes=(0, 0, None))
+    want = jax.jit(jax.vmap(inner, in_axes=(0, 0, outer)))(G, target, b_max)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    if kind == "unreachable":
+        np.testing.assert_array_equal(_bits(got), _bits(b_max_full))
+
+
+@pytest.mark.parametrize("cells,cands", [(3, 21), (2, 351)])
+def test_solve_lane_dense_bitwise_tiled(cells, cands, monkeypatch):
+    """Double-vmapped Algorithm 2 and whole solves (cells x candidates x
+    N = 50) whose f search runs its inversions lane-dense give bitwise the
+    b, f, p, t, R and b_sum of the same search vmapped as laid out (the
+    parent's program)."""
+    spec = wireless.ScenarioSpec()
+    rng = np.random.default_rng(1)
+    consts, args = [], []
+    for seed in range(cells):
+        scn = wireless.draw_scenario(seed, spec)
+        assigns = jnp.asarray(rng.integers(0, spec.M, (cands, spec.N)),
+                              jnp.int32)
+        consts.append(system_model.sroa_constants_batched(scn, assigns))
+        args.append((scn.B_open, scn.f_max, scn.p_max, scn.N0))
+    consts = jax.tree.map(lambda *x: jnp.stack(x), *consts)
+    B, f_max, p_max, N0 = (jnp.stack(a) for a in zip(*args))
+    p = jnp.broadcast_to(p_max[:, None, :], consts.h.shape)
+    t = jnp.full(consts.h.shape[:2], 50.0, jnp.float32)
+
+    cfg = sroa.SroaConfig(b_iters=30, f_iters=6, p_iters=4, t_iters=4)
+
+    def run(floor):
+        # Neither route may reuse the other's traced program.
+        jax.clear_caches()
+        monkeypatch.setattr(sroa, "_DENSE_MIN_TILES", floor)
+
+        def solve(c, B, f_max, p_max, N0):
+            return sroa.solve_constants_impl(c, B, B, f_max, p_max, N0,
+                                             jnp.float32(LAM), cfg)
+
+        def alg2(c, p, t, B, f_max, N0):
+            return sroa.algorithm2(c, p, t, B, B, f_max, N0, cfg)
+        solves = jax.jit(jax.vmap(jax.vmap(solve,
+                                           in_axes=(0,) + (None,) * 4)))
+        alg2s = jax.jit(jax.vmap(jax.vmap(alg2,
+                                          in_axes=(0, 0, 0) + (None,) * 3)))
+        loops = _inversion_loops(
+            alg2s.lower(consts, p, t, B, f_max, N0).as_text())
+        out = solves(consts, B, f_max, p_max, N0)
+        b, f, b_sum = alg2s(consts, p, t, B, f_max, N0)
+        return loops, (out.b, out.f, out.p, out.t, out.R, out.b_sum,
+                       b, f, b_sum)
+
+    rows = -(-cells * cands * spec.N // 1024) * 8
+    loops, got = run(1)
+    assert loops == [f"{rows}x128"] * 2
+    loops, want = run(10 ** 9)
+    assert loops == [f"{cells}x{cands}x{spec.N}"] * 2
+    assert bool(jnp.all(jnp.isfinite(got[4])))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("batch", [None, (16,)])
+def test_inversion_shape_rule_keeps_small_batches_tiled(batch):
+    """An unbatched (N,) search and the re-pricing's (16, 50) single-vmap
+    batch keep the parent's program; the engine's buckets go lane-dense."""
+    shape = (batch or ()) + (50,)
+    G, target, b_max, _ = _inversion_case("reachable", shape)
+    cfg = sroa.SroaConfig(b_iters=30, f_iters=5)
+    u = jnp.ones(shape, jnp.float32)
+    consts = system_model.SroaConstants(A=u, J=u, H=u * 1e4, delta=u * 0.1,
+                                        h=G, E_cloud_total=u[..., 0])
+
+    def alg2(c, B):
+        return sroa.algorithm2(c, jnp.ones((50,)), 50.0, B, B,
+                               jnp.full((50,), 1e9), 1.0, cfg)
+
+    fn = jax.jit(alg2 if batch is None else jax.vmap(alg2))
+    B = b_max if batch is None else jnp.broadcast_to(b_max, batch)
+    loops = _inversion_loops(fn.lower(consts, B).as_text())
+    assert loops == ["x".join(map(str, shape))] * 2
+    if batch is not None:
+        assert sroa.invert_tiles(shape) == (800, 2, False)
+    for rows, cands in [(4, 201), (8, 201), (16, 201), (8, 351), (4, 301)]:
+        elements, tiles, dense = sroa.invert_tiles((rows, cands, 50))
+        assert dense and elements / (tiles * 1024) > 0.97
 
 
 # -------------------------------------------------------------------- SROA

@@ -6,6 +6,7 @@ All service fixtures share one (C=4, N=8, M=2) shape and one SroaConfig
 so the engine/allocator compile once per test session.
 """
 import dataclasses
+import re
 import subprocess
 import sys
 import threading
@@ -451,12 +452,61 @@ def test_research_counters_read_the_engine_result():
     rounds = np.asarray(outs[0].rounds)
     escapes = np.asarray(outs[0].escapes)
     assert rounds.shape == (4,)
+    # The bucket's scoring inversions, (rows, 1 + N (M - 1) candidates, N),
+    # and its final solve's, (rows, N): too small to go lane-dense.
+    shapes = fengine.inversion_batches(4, 8, 2)
+    assert shapes == [(4, 9, 8), (4, 8)]
+    elements = sum(sroa.invert_tiles(s)[0] for s in shapes)
+    lanes = sum(sroa.invert_tiles(s)[1] for s in shapes) * sroa.TILE
     assert tick.counters == {
         "research.trip": int(rounds.max()),
         "research.row_rounds": int(rounds[:3].sum()),
         "research.rows": 4, "research.cells": 3,
-        "research.escapes": int(escapes[:3].sum())}
+        "research.escapes": int(escapes[:3].sum()),
+        "research.invert_elements": elements,
+        "research.invert_lanes": lanes}
+    assert (elements, lanes) == (4 * 9 * 8 + 4 * 8, (8 + 1) * 1024)
     assert svc.telemetry.counters == tick.counters
+
+
+@pytest.mark.parametrize("ladder_on,cands", [(False, 9), (True, 25)])
+def test_research_counts_the_lane_dense_inversion(monkeypatch, ladder_on,
+                                                  cands):
+    """With the shape rule's floor lowered so that a tiny bucket goes
+    lane-dense, each re-search tick counts the elements and tile lanes of
+    its Algorithm 2 inversions, those of the loops its program holds: the
+    scoring's (4, A, 8) in one (8, 128) tile, A = 1 + N (M - 1), plus
+    N (L - 1) with an L-rung ladder, and the final solve's (4, 8) as laid
+    out.  At the default floor the re-pricing of 16 cells of 50 users
+    keeps its (16, 50) layout."""
+    from repro.fed import compression as comp_lib
+    ladder = comp_lib.default_ladder() if ladder_on else None
+    jax.clear_caches()          # no program traced under another floor
+    monkeypatch.setattr(sroa, "_DENSE_MIN_TILES", 1)
+    svc = make_service(replan_all=True, ladder=ladder)
+    for _ in range(2):
+        svc.tick()
+        got = svc.telemetry.last_tick.counters
+        assert (got["research.invert_elements"],
+                got["research.invert_lanes"]) == (4 * cands * 8 + 4 * 8,
+                                                  2048)
+    loops = _inversion_loops(_engine_program(ladder).as_text())
+    assert {"8x128", "4x8"} <= set(loops)
+    assert {x for x in loops if x.endswith("x128")} == {"8x128"}
+    monkeypatch.undo()
+    jax.clear_caches()
+    fleet = fbatch.draw_fleet(0, 16, wireless.ScenarioSpec(),
+                              n_range=(50, 50))
+    loops = _inversion_loops(_reprice_program(None, fleet).as_text())
+    assert set(loops) == {"16x50"}
+
+
+def _inversion_loops(text):
+    """Shapes of SROA's one-trip inversion loops in a lowered program (each
+    carries G, target, its flag and the upper bound)."""
+    return re.findall(r"stablehlo\.while\(.*\) : tensor<([\dx]+)xf32>, "
+                      r"tensor<\1xf32>, tensor<i1>, tensor<\1xf32>$", text,
+                      re.M)
 
 
 def _engine_program(ladder):
@@ -466,8 +516,8 @@ def _engine_program(ladder):
         ladder=ladder)
 
 
-def _reprice_program(_):
-    fleet = make_fleet()
+def _reprice_program(_, fleet=None):
+    fleet = make_fleet() if fleet is None else fleet
     return jax.jit(fbatch.solve_batch, static_argnames=("cfg", "ladder")
                    ).lower(fleet, fbatch.fleet_assignments(fleet), LAM, CFG)
 

@@ -13,6 +13,7 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
+import math
 import os
 import re
 
@@ -133,6 +134,56 @@ def test_algorithm2_inversion_fuses_for_v5e(one_chip):
     assert len(inner) == 2
     for body in inner:     # one log1p per bisection step
         assert _count_in(comps, body, "log-plus-one") >= cfg.b_iters
+
+
+@pytest.mark.parametrize("batch,carry,flattens", [
+    ((16, CANDIDATES), "1264,128", 1),  # 16 x 201 x 50 in 158 whole tiles
+    ((16,), "16,50", 0),                # the re-pricing keeps its layout
+], ids=["engine", "reprice"])
+def test_algorithm2_inversion_lane_dense_for_v5e(one_chip, batch, carry,
+                                                 flattens):
+    """The engine's scoring of a 16-row bucket runs each inversion loop on
+    a lane-dense (rows, 128) stream of the whole batch, and each step of
+    the f search streams only its target in (G and b_max stream once per
+    search); the re-pricing's one vmap over 16 cells keeps the (cells, N)
+    tiles.  Either way each problem's b_sum reduces its users' axis of
+    the (..., N) layout."""
+    cfg = sroa.SroaConfig(b_iters=30, f_iters=24)
+    C = batch[0]
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    u = sds(*batch, N)
+    consts = SroaConstants(A=u, J=u, H=u, delta=u, h=u,
+                           E_cloud_total=sds(*batch))
+
+    def fn(consts, p, t, B, f_max, N0):
+        def alg2(c, p, t, B, f_max, N0):
+            return sroa.algorithm2(c, p, t, B, B, f_max, N0, cfg)
+        for _ in batch[1:]:
+            alg2 = jax.vmap(alg2, in_axes=(0, 0, 0, None, None, None))
+        return jax.vmap(alg2)(consts, p, t, B, f_max, N0)
+
+    text = jax.jit(fn).lower(consts, u, sds(*batch), sds(C), sds(C, N),
+                             sds(C)).compile().as_text()
+    # The inversion's loops carry (flag, lo/hi bound, G, target).
+    carries = [m.group(1) for line in text.splitlines() if " while(" in line
+               for m in [re.search(r"= \(pred\[\][^,]*, f32\[([\d,]+)\]",
+                                   line)] if m]
+    assert carries == [carry, carry]
+    # b_sum is still summed over each problem's N users, in their layout.
+    sums = re.findall(r"= f32\[([\d,]+)\]\S* reduce\(.*?dimensions="
+                      r"\{(\d+)\}.*?reduce_sum", text)
+    assert set(sums) == {(",".join(map(str, batch)), str(len(batch)))}
+    comps = _computations(text)
+    f_step = [b for b in re.findall(r"\bwhile\(.*?body=%([\w.-]+)", text)
+              if _count_in(comps, b, "while")]
+    # What the f step flattens into the stream: its target alone.
+    flat = rf"= f32\[{N * math.prod(batch)}\]\S* reshape\("
+    assert len(f_step) == 1
+    assert sum(bool(re.search(flat, line))
+               for line in comps[f_step[0]]) == flattens
 
 
 def test_sroa_bisect_vec_compiles_for_v5e(one_chip):
